@@ -1,7 +1,8 @@
 """qwen3-0.6b [dense] — qk_norm, GQA.
 
-[hf:Qwen/Qwen3-8B; hf]
-28L d_model=1024 16H (GQA kv=8) d_ff=3072 vocab=151936.
+[hf:Qwen/Qwen3-0.6B (config.json)]
+28L d_model=1024 16H (GQA kv=8) head_dim=128 d_ff=3072 vocab=151936,
+rms_norm_eps=1e-6.
 """
 from repro.configs.base import ModelConfig
 
@@ -16,8 +17,10 @@ def config() -> ModelConfig:
         d_model=1024,
         n_heads=16,
         n_kv_heads=8,
+        head_dim=128,
         d_ff=3072,
         vocab_size=151936,
+        norm_eps=1e-6,
         qk_norm=True,
         tie_embeddings=True,
         rope_theta=1_000_000.0,

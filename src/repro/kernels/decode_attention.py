@@ -1,26 +1,14 @@
-"""Pallas TPU kernel: length-aware split-KV decode attention (flash-decoding).
+"""Pallas TPU decode attention: one query token per slot against the cache.
 
-One query token per slot attends a slotted KV cache laid out (B, S, Hkv, hd).
-Grid (B, Hkv, S/bk) with the KV-sequence axis innermost: the online-softmax
-accumulators (m, l, acc) live in VMEM scratch across the KV loop, exactly like
-``flash_attention.py`` — but causality here is *per slot*: each batch row
-carries its own visible limit ``start`` (the absolute position of the query),
-and every KV block strictly beyond that limit is skipped via ``pl.when``, so
-a slot that is 40 tokens into a 4096-slot cache issues work for one block,
-not thirty-two. That block skip is what makes decode cost track *actual*
-sequence length instead of cache capacity.
-
-INT8 KV path: ``k``/``v`` arrive as int8 with per-(pos, head) f32 scales. The
-dequant is fused into the epilogue — scores are scaled by ``k_s`` after the
-QK^T dot and probabilities by ``v_s`` before the PV dot — so the cache is
-only ever read as int8 (half the HBM stream of bf16) and no dequantized KV
-tile is ever materialized. The ``l`` normalizer accumulates the *unscaled*
-probabilities: out = (Σ p·v_s·v) / (Σ p) == softmax(s)·v_s·v, matching the
-XLA fallback's probability-side dequant bit-for-tolerance.
-
-GQA: the G = Hq/Hkv query heads sharing one KV head form the row axis of
-every score tile, so the kernel's dots are (G, hd)x(hd, bk) and (G, bk)x(bk,
-hd) — the KV block is read once per group, not once per query head.
+Decode is the Sq=1 case of the cache-continuation kernel in
+``prefill_attention.py`` (split-KV flash decoding over the KV-block grid
+axis): each batch row carries its own visible limit ``start`` (the absolute
+position of the query), and every KV block strictly beyond that limit is
+skipped via ``pl.when`` and never fetched, so a slot that is 40 tokens into
+a 4096-slot cache issues work for its first few blocks only. That block
+skip is what makes decode cost track *actual* sequence length instead of
+cache capacity. The INT8 KV path fuses the dequant into the score and
+probability tiles (see the kernel module).
 """
 from __future__ import annotations
 
@@ -28,127 +16,22 @@ import functools
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.kv_layout import (CompilerParams as _CompilerParams,
-                                     NEG_INF, from_store, pad_kv_blocks,
-                                     transpose_scales)
-
-
-def _body(start, q_ref, k_ref, v_ref, rest, *, bk: int, n_kv: int,
-          scale: float, quantized: bool):
-    """Shared online-softmax body. ``start`` is this row's query position
-    (already read from whichever ref layout the wrapper uses); the KV refs
-    hold one bk-long block of LOGICAL positions j*bk..(j+1)*bk-1 — the
-    contiguous wrapper blocks a (B, S, Hkv, hd) cache, the paged wrapper a
-    (n_pages, page_size, Hkv, hd) arena with bk == page_size and the block
-    index taken from the page table, and the body cannot tell the
-    difference (same block shapes, same logical positions)."""
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(j * bk <= start)                     # block intersects the window
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)       # (G, hd)
-        # int8 reads as-is (dequant on scores); uint16 paged-arena blocks
-        # bitcast back to bf16 (from_store) before the f32 upcast
-        k = from_store(k_ref[0, :, 0]).astype(jnp.float32)    # (bk, hd)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if quantized:
-            s = s * ks_ref[0, 0][None, :]         # dequant on scores, not KV
-        kv_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        s = jnp.where(kv_pos <= start, s, NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_prev * corr + jnp.sum(p, axis=1)
-        if quantized:
-            p = p * vs_ref[0, 0][None, :]         # dequant on probabilities
-        acc_ref[...] = (acc_ref[...] * corr[:, None]
-                        + jax.lax.dot_general(
-                            p, from_store(v_ref[0, :, 0]).astype(jnp.float32),
-                            (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
-        m_ref[...] = m_new
-
-    @pl.when(j == n_kv - 1)
-    def _finalize():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)[:, None]
-                       ).astype(o_ref.dtype)
-
-
-def _kernel(start_ref, q_ref, k_ref, v_ref, *rest, bk: int, n_kv: int,
-            scale: float, quantized: bool):
-    _body(start_ref[0, 0], q_ref, k_ref, v_ref, rest, bk=bk, n_kv=n_kv,
-          scale=scale, quantized=quantized)
-
-
-def _paged_kernel(tbl_ref, start_ref, q_ref, k_ref, v_ref, *rest, bk: int,
-                  n_kv: int, scale: float, quantized: bool):
-    # tbl_ref/start_ref are SMEM scalar-prefetch refs: the table drives the
-    # BlockSpec index maps (never read here), start indexes by batch row
-    _body(start_ref[pl.program_id(0)], q_ref, k_ref, v_ref, rest, bk=bk,
-          n_kv=n_kv, scale=scale, quantized=quantized)
+from repro.kernels.prefill_attention import (paged_prefill_attention_pallas,
+                                             prefill_attention_pallas)
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
 def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                             k_s: Optional[jax.Array] = None,
                             v_s: Optional[jax.Array] = None,
-                            start: jax.Array = None, *, bk: int = 128,
+                            start: jax.Array = None, *, bk: int = 16,
                             interpret: bool = False) -> jax.Array:
     """q: (B, Hq, hd); k/v: (B, S, Hkv, hd) float or int8 (then k_s/v_s
     (B, S, Hkv) f32 scales); start: (B,) int32 per-slot query positions.
     Returns (B, Hq, hd) bf16."""
-    b, hq, hd = q.shape
-    s_len, hkv = k.shape[1], k.shape[2]
-    g = hq // hkv
-    bk = min(bk, s_len)
-    k, v, k_s, v_s, n_kv = pad_kv_blocks(k, v, k_s, v_s, bk)
-    quantized = k_s is not None
-
-    inputs = [jnp.reshape(start, (b, 1)).astype(jnp.int32),
-              q.reshape(b, hkv, g, hd), k, v]
-    in_specs = [
-        pl.BlockSpec((1, 1), lambda bb, h, j: (bb, 0)),
-        pl.BlockSpec((1, 1, g, hd), lambda bb, h, j: (bb, h, 0, 0)),
-        pl.BlockSpec((1, bk, 1, hd), lambda bb, h, j: (bb, j, h, 0)),
-        pl.BlockSpec((1, bk, 1, hd), lambda bb, h, j: (bb, j, h, 0)),
-    ]
-    if quantized:
-        inputs += list(transpose_scales(k_s, v_s))
-        in_specs += [pl.BlockSpec((1, 1, bk), lambda bb, h, j: (bb, h, j)),
-                     pl.BlockSpec((1, 1, bk), lambda bb, h, j: (bb, h, j))]
-
-    out = pl.pallas_call(
-        functools.partial(_kernel, bk=bk, n_kv=n_kv, scale=hd ** -0.5,
-                          quantized=quantized),
-        grid=(b, hkv, n_kv),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda bb, h, j: (bb, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), jnp.bfloat16),
-        scratch_shapes=[pltpu.VMEM((g,), jnp.float32),
-                        pltpu.VMEM((g,), jnp.float32),
-                        pltpu.VMEM((g, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(*inputs)
-    return out.reshape(b, hq, hd)
+    return prefill_attention_pallas(q[:, None], k, v, k_s, v_s, start, bq=1,
+                                    bk=bk, interpret=interpret)[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -158,55 +41,10 @@ def paged_decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                                   start: jax.Array = None,
                                   pages: jax.Array = None, *,
                                   interpret: bool = False) -> jax.Array:
-    """Page-table-indirect split-KV decode: q (B, Hq, hd) vs a PAGED arena.
-
-    k/v: (n_pages, page_size, Hkv, hd) float or int8 (then k_s/v_s
-    (n_pages, page_size, Hkv) f32 scales); start: (B,) int32; pages:
-    (B, n_blk) int32 — the window prefix of each row's page table. The KV
-    block size is pinned to ``page_size``, so grid step (b, h, j) DMAs
-    physical page ``pages[b, j]`` via a scalar-prefetch index map — same
-    body, block shapes, and logical-position skip/mask as the contiguous
-    kernel, only the block index indirects. Unallocated table entries point
-    at physical page 0 (the trash page) and sit beyond every causal limit.
-    Returns (B, Hq, hd) bf16."""
-    b, hq, hd = q.shape
-    ps, hkv = k.shape[1], k.shape[2]
-    g = hq // hkv
-    n_blk = pages.shape[1]
-    quantized = k_s is not None
-
-    inputs = [q.reshape(b, hkv, g, hd), k, v]
-    in_specs = [
-        pl.BlockSpec((1, 1, g, hd), lambda bb, h, j, tbl, st: (bb, h, 0, 0)),
-        pl.BlockSpec((1, ps, 1, hd),
-                     lambda bb, h, j, tbl, st: (tbl[bb, j], 0, h, 0)),
-        pl.BlockSpec((1, ps, 1, hd),
-                     lambda bb, h, j, tbl, st: (tbl[bb, j], 0, h, 0)),
-    ]
-    if quantized:
-        inputs += list(transpose_scales(k_s, v_s))   # (n_pages, Hkv, ps)
-        in_specs += [pl.BlockSpec((1, 1, ps),
-                                  lambda bb, h, j, tbl, st: (tbl[bb, j], h, 0))
-                     ] * 2
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv, n_blk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda bb, h, j, tbl, st: (bb, h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((g,), jnp.float32),
-                        pltpu.VMEM((g,), jnp.float32),
-                        pltpu.VMEM((g, hd), jnp.float32)],
-    )
-    out = pl.pallas_call(
-        functools.partial(_paged_kernel, bk=ps, n_kv=n_blk,
-                          scale=hd ** -0.5, quantized=quantized),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), jnp.bfloat16),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(pages.astype(jnp.int32),
-      jnp.asarray(start, jnp.int32).reshape(b), *inputs)
-    return out.reshape(b, hq, hd)
+    """Page-table-indirect decode: q (B, Hq, hd) vs a PAGED arena k/v
+    (n_pages, page_size, Hkv, hd) (scales (n_pages, page_size, Hkv)),
+    start (B,) int32, pages (B, n_blk) int32 — see
+    ``paged_prefill_attention_pallas``. Returns (B, Hq, hd) bf16."""
+    return paged_prefill_attention_pallas(q[:, None], k, v, k_s, v_s, start,
+                                          pages, bq=1,
+                                          interpret=interpret)[:, 0]

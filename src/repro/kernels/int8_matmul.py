@@ -19,10 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed across jax versions (TPUCompilerParams -> CompilerParams)
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref, *, n_k: int):
     @pl.when(pl.program_id(2) == 0)
@@ -36,7 +32,7 @@ def _kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref, *, n_k: int):
     @pl.when(pl.program_id(2) == n_k - 1)
     def _epilogue():
         acc = acc_ref[...].astype(jnp.float32)
-        scale = xs_ref[...][:, None] * ws_ref[...][None, :]
+        scale = xs_ref[...] * ws_ref[...]           # (bm, 1) x (1, bn)
         o_ref[...] = (acc * scale).astype(o_ref.dtype)
 
 
@@ -44,7 +40,11 @@ def _kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref, *, n_k: int):
 def int8_matmul_pallas(x_q: jax.Array, w_q: jax.Array, x_scale: jax.Array,
                        w_scale: jax.Array, *, bm: int = 256, bn: int = 256,
                        bk: int = 512, interpret: bool = False) -> jax.Array:
-    """x_q: (M, K) int8; w_q: (K, N) int8; x_scale: (M,); w_scale: (N,)."""
+    """x_q: (M, K) int8; w_q: (K, N) int8; x_scale: (M,); w_scale: (N,).
+
+    The scales enter the kernel as (M, 1) / (1, N) columns and rows: a 1-D
+    block has a different tiled layout in Mosaic than XLA gives the array,
+    and the TPU compiler refuses the mismatch."""
     m, k = x_q.shape
     k2, n = w_q.shape
     assert k == k2
@@ -57,6 +57,8 @@ def int8_matmul_pallas(x_q: jax.Array, w_q: jax.Array, x_scale: jax.Array,
         w_q = jnp.pad(w_q, ((0, pk), (0, pn)))
         w_scale = jnp.pad(w_scale, (0, pn))
     mp, kp, np_ = m + pm, k + pk, n + pn
+    x_scale = x_scale.reshape(mp, 1)
+    w_scale = w_scale.reshape(1, np_)
     n_k = kp // bk
 
     out = pl.pallas_call(
@@ -65,13 +67,13 @@ def int8_matmul_pallas(x_q: jax.Array, w_q: jax.Array, x_scale: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bm,), lambda i, j, kk: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((bm, 1), lambda i, j, kk: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.bfloat16),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_q, w_q, x_scale, w_scale)

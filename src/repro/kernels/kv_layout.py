@@ -1,11 +1,10 @@
 """Layout helpers shared by the cache-attention Pallas kernels.
 
-``decode_attention.py`` (Sq=1, split-KV) and ``prefill_attention.py``
-(Sq>1, cache continuation) read the same slotted (B, S, Hkv, hd) KV cache
-and share the plumbing that is easy to let drift: the jax-version compat
-shim for compiler params, the KV-tail block padding, and the INT8 scale
-transpose. Keeping these here means a jax rename or a scale-layout fix
-lands in both serving hot paths at once.
+``decode_attention.py`` (Sq=1) and ``prefill_attention.py`` (Sq>=1, cache
+continuation) read the same KV cache through ONE kernel, which walks a
+PAGED arena: a contiguous (B, S, Hkv, hd) cache is handed to it as an arena
+of ``bk``-position pages with an identity page table (``as_pages``), so the
+contiguous and paged wrappers differ only in where the table comes from.
 
 This module also owns the PAGED layout's logical<->physical index math,
 shared by all three backends (DESIGN.md §12). A paged KV arena drops the
@@ -20,7 +19,7 @@ three consumers:
     prefix, so windowed numerics are bit-identical by construction);
   * ``scatter_pages``     — the write path (``models.attention``): flat
     per-element scatter through the same table;
-  * the Pallas kernels skip the gather entirely — the KV-block grid axis
+  * the Pallas kernel skips the gather entirely — the KV-block grid axis
     walks the table via scalar-prefetch BlockSpec index maps with the block
     size pinned to ``page_size``, so block j's physical index IS
     ``table[b, j]``.
@@ -31,37 +30,39 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
-
-# renamed across jax versions (TPUCompilerParams -> CompilerParams)
-CompilerParams = getattr(pltpu, "CompilerParams",
-                         getattr(pltpu, "TPUCompilerParams", None))
 
 NEG_INF = -1e30
 
 
-def pad_kv_blocks(k: jax.Array, v: jax.Array, k_s: Optional[jax.Array],
-                  v_s: Optional[jax.Array], bk: int) -> Tuple:
-    """Zero-pad the KV sequence axis (axis 1) to a ``bk`` multiple.
-
-    The padded tail sits at positions beyond any real row's causal limit,
-    so the kernels' position masks neutralize it exactly (exp(-inf) = +0.0
-    contributions). Returns (k, v, k_s, v_s, n_kv_blocks)."""
-    s_len = k.shape[1]
+def as_pages(k: jax.Array, v: jax.Array, k_s: Optional[jax.Array],
+             v_s: Optional[jax.Array], bk: int) -> Tuple:
+    """View a contiguous (B, S, Hkv, hd) cache as a paged arena of
+    ``bk``-position pages: (B*n, bk, Hkv, hd) leaves (scales (B*n, bk,
+    Hkv)) plus the identity (B, n) page table. The sequence axis is
+    zero-padded to a ``bk`` multiple first; the padded tail sits beyond
+    every real row's causal limit, so the kernel's position mask
+    neutralizes it exactly (exp(-inf) = +0.0 contributions). Returns
+    (k, v, k_s, v_s, pages)."""
+    b, s_len = k.shape[:2]
     pk = (-s_len) % bk
-    if pk:
-        k = jnp.pad(k, ((0, 0), (0, pk), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0)))
-        if k_s is not None:
-            k_s = jnp.pad(k_s, ((0, 0), (0, pk), (0, 0)))
-            v_s = jnp.pad(v_s, ((0, 0), (0, pk), (0, 0)))
-    return k, v, k_s, v_s, (s_len + pk) // bk
+    n = (s_len + pk) // bk
+
+    def page(t):
+        if t is None:
+            return None
+        if pk:
+            t = jnp.pad(t, ((0, 0), (0, pk)) + ((0, 0),) * (t.ndim - 2))
+        return t.reshape((b * n, bk) + t.shape[2:])
+    pages = jnp.arange(b * n, dtype=jnp.int32).reshape(b, n)
+    return page(k), page(v), page(k_s), page(v_s), pages
 
 
-def transpose_scales(k_s: jax.Array, v_s: jax.Array) -> Tuple:
-    """(B, S, Hkv) f32 dequant scales -> (B, Hkv, S): the sequence axis
-    lands on lanes, so a (1, 1, bk) block per grid step is contiguous."""
-    return jnp.transpose(k_s, (0, 2, 1)), jnp.transpose(v_s, (0, 2, 1))
+def page_scales(s: jax.Array) -> jax.Array:
+    """(n_pages, page_size, Hkv) f32 dequant scales -> (n_pages, Hkv,
+    page_size): a page's scales for all heads form one (Hkv, page_size)
+    block — both dims whole, as the TPU tiling rule requires — and head
+    ``h``'s row broadcasts over a score tile's rows in the kernel."""
+    return jnp.transpose(s, (0, 2, 1))
 
 
 # ------------------------------------------------------------------- paged

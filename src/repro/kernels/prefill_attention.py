@@ -1,13 +1,23 @@
 """Pallas TPU kernel: fused chunked-prefill (cache-continuation) attention.
 
-The Sq>1 generalization of ``decode_attention.py``: a chunk of Sq query
-tokens per slot attends the slotted KV cache laid out (B, W, Hkv, hd), where
-W is the static visible window the caller already sliced. Grid
-(B, Hkv, Sq/bq, W/bk) with the KV-sequence axis innermost: the online-softmax
-accumulators (m, l, acc) live in VMEM scratch across the KV loop per query
-tile, so no (B, Sq, Hkv, G, W) score tensor is ever materialized — the
-masked-einsum prefill this replaces was the engine's TTFT bottleneck
-precisely because it built that tensor per chunk.
+The one cache-attention kernel: a chunk of Sq query tokens per slot attends
+a PAGED KV arena (n_pages, page_size, Hkv, hd) through a per-slot page table
+— a contiguous (B, W, Hkv, hd) cache is handed over as an arena of ``bk``
+pages with an identity table (``kv_layout.as_pages``), and decode is the
+Sq=1 chunk (``decode_attention.py``). Grid (B, Sq/bq, n_blk) with the
+KV-block axis innermost; the online-softmax accumulators (m, l, acc) live
+in VMEM scratch across the KV loop per query tile, so no (B, Sq, Hkv, G, W)
+score tensor is ever materialized.
+
+Block layout (DESIGN.md §Backend-registry): every block keeps the arrays'
+last two dims whole, as the TPU tiling rule requires of dims that are not
+(8, 128) multiples — a q/out tile is (1, bq, Hq, hd), a KV page (1, ps, Hkv,
+hd), a scale page (1, Hkv, ps). The kernel walks the heads itself: each KV
+head's (ps, hd) slice is read once and serves its G = Hq/Hkv query heads
+(GQA), each query head's (bq, hd) rows run their own online softmax.
+``start`` and the page table are scalar-prefetch (SMEM) operands; the table
+drives the KV index maps, which also clamp the block index to the tile's
+last visible page so pages past the causal limit are never DMA'd.
 
 Causality is *absolute*, per slot: each batch row carries ``start`` (the
 chunk's first absolute position) and query i of the chunk sees exactly cache
@@ -17,16 +27,17 @@ the window bucket — chunk N of a prompt attends chunks 0..N with the same
 per-row arithmetic as a whole-prompt prefill: KV blocks fully beyond a row's
 limit contribute exact no-ops (p == +0.0, corr == 1.0) when visited and are
 skipped entirely via ``pl.when`` when the whole tile is past them, so chunked
-and whole-prompt prefill are *bit-consistent* row for row.
+and whole-prompt prefill are *bit-consistent* row for row. The block size is
+part of the arithmetic (the online softmax folds one block at a time): a
+contiguous cache read with ``bk`` equal to a paged arena's page size gives
+the paged result bit for bit.
 
-INT8 KV path: identical epilogue placement to the decode kernel — ``k``/``v``
-are read as int8, per-(pos, head) ``k_s`` scales the score tile after QK^T,
-``v_s`` scales the probability tile before PV, and the ``l`` normalizer
-accumulates unscaled probabilities. No dequantized KV tile ever exists.
-
-GQA: the G = Hq/Hkv query heads sharing a KV head are folded into the query
-tile's row axis — dots are (bq*G, hd)x(hd, bk) and (bq*G, bk)x(bk, hd), one
-KV block read per group per tile.
+INT8 KV path: ``k``/``v`` are read as int8, per-(pos, head) ``k_s`` scales
+the score tile after QK^T, ``v_s`` scales the probability tile before PV,
+and the ``l`` normalizer accumulates unscaled probabilities:
+out = (Σ p·v_s·v) / (Σ p) == softmax(s)·v_s·v. No dequantized KV tile ever
+exists. A uint16 arena holds raw bf16 words and is bitcast back
+(``from_store``) before the f32 upcast.
 """
 from __future__ import annotations
 
@@ -38,23 +49,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.kv_layout import (CompilerParams as _CompilerParams,
-                                     NEG_INF, from_store, pad_kv_blocks,
-                                     transpose_scales)
+from repro.kernels.kv_layout import NEG_INF, as_pages, from_store, page_scales
 
 
-def _body(start, q_ref, k_ref, v_ref, rest, *, bq: int, bk: int, g: int,
-          n_kv: int, scale: float, quantized: bool):
-    """Shared online-softmax body; ``start`` is this row's chunk-start
-    position, already read by the wrapper. KV refs hold one bk-long block
-    of LOGICAL positions j*bk..(j+1)*bk-1 — contiguous blocking or a paged
-    arena with bk == page_size and the block index from the page table; the
-    body is layout-blind (see ``decode_attention._body``)."""
+def _kernel(tbl_ref, start_ref, q_ref, k_ref, v_ref, *rest, bq: int, bk: int,
+            g: int, n_kv: int, scale: float, quantized: bool):
+    del tbl_ref                     # consumed by the KV index maps only
     if quantized:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
-    i, j = pl.program_id(2), pl.program_id(3)
+    start = start_ref[pl.program_id(0)]
+    i, j = pl.program_id(1), pl.program_id(2)
+    hq, hkv = q_ref.shape[2], k_ref.shape[2]
 
     @pl.when(j == 0)
     def _init():
@@ -67,113 +74,38 @@ def _body(start, q_ref, k_ref, v_ref, rest, *, bq: int, bk: int, g: int,
     # exact no-ops for that row via the position mask below
     @pl.when(j * bk <= start + (i + 1) * bq - 1)
     def _compute():
-        q = q_ref[0, :, 0].astype(jnp.float32).reshape(bq * g, -1)
-        # int8 reads as-is (dequant on scores); uint16 paged-arena blocks
-        # bitcast back to bf16 (from_store) before the f32 upcast
-        k = from_store(k_ref[0, :, 0]).astype(jnp.float32)    # (bk, hd)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if quantized:
-            s = s * ks_ref[0, 0][None, :]         # dequant on scores, not KV
-        # row r of the tile is query (i*bq + r//g) at absolute position
-        # start + i*bq + r//g; 3-D iota then reshape avoids an integer div
-        q_pos = (start + i * bq
-                 + jax.lax.broadcasted_iota(jnp.int32, (bq, g, bk), 0
-                                            ).reshape(bq * g, bk))
-        kv_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq * g, bk), 1)
-        s = jnp.where(kv_pos <= q_pos, s, NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_prev * corr + jnp.sum(p, axis=1)
-        if quantized:
-            p = p * vs_ref[0, 0][None, :]         # dequant on probabilities
-        acc_ref[...] = (acc_ref[...] * corr[:, None]
-                        + jax.lax.dot_general(
-                            p, from_store(v_ref[0, :, 0]).astype(jnp.float32),
-                            (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
-        m_ref[...] = m_new
+        q_pos = start + i * bq + jax.lax.broadcasted_iota(jnp.int32,
+                                                          (bq, bk), 0)
+        kv_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        visible = kv_pos <= q_pos
+        for h in range(hkv):
+            k = from_store(k_ref[0, :, h, :]).astype(jnp.float32)  # (bk, hd)
+            v = from_store(v_ref[0, :, h, :]).astype(jnp.float32)
+            for r in range(h * g, (h + 1) * g):
+                q = q_ref[0, :, r, :].astype(jnp.float32)           # (bq, hd)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if quantized:
+                    s = s * ks_ref[0, h:h + 1, :]   # dequant on scores
+                s = jnp.where(visible, s, NEG_INF)
+                m_prev, l_prev = m_ref[r], l_ref[r]                 # (bq, 1)
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                corr = jnp.exp(m_prev - m_new)
+                l_ref[r] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+                if quantized:
+                    p = p * vs_ref[0, h:h + 1, :]   # dequant on probabilities
+                acc_ref[r] = acc_ref[r] * corr + jax.lax.dot_general(
+                    p, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_ref[r] = m_new
 
     @pl.when(j == n_kv - 1)
     def _finalize():
-        o_ref[0, :, 0] = (acc_ref[...]
-                          / jnp.maximum(l_ref[...], 1e-30)[:, None]
-                          ).reshape(bq, g, acc_ref.shape[-1]
-                                    ).astype(o_ref.dtype)
-
-
-def _kernel(start_ref, q_ref, k_ref, v_ref, *rest, bq: int, bk: int, g: int,
-            n_kv: int, scale: float, quantized: bool):
-    _body(start_ref[0, 0], q_ref, k_ref, v_ref, rest, bq=bq, bk=bk, g=g,
-          n_kv=n_kv, scale=scale, quantized=quantized)
-
-
-def _paged_kernel(tbl_ref, start_ref, q_ref, k_ref, v_ref, *rest, bq: int,
-                  bk: int, g: int, n_kv: int, scale: float, quantized: bool):
-    # tbl_ref/start_ref are SMEM scalar-prefetch refs: the table drives the
-    # BlockSpec index maps (never read here), start indexes by batch row
-    _body(start_ref[pl.program_id(0)], q_ref, k_ref, v_ref, rest, bq=bq,
-          bk=bk, g=g, n_kv=n_kv, scale=scale, quantized=quantized)
-
-
-@functools.partial(jax.jit, static_argnames=("bq", "bk", "interpret"))
-def prefill_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
-                             k_s: Optional[jax.Array] = None,
-                             v_s: Optional[jax.Array] = None,
-                             start: jax.Array = None, *, bq: int = 16,
-                             bk: int = 128,
-                             interpret: bool = False) -> jax.Array:
-    """q: (B, Sq, Hq, hd) queries at absolute positions start..start+Sq-1;
-    k/v: (B, W, Hkv, hd) float or int8 (then k_s/v_s (B, W, Hkv) f32 scales);
-    start: (B,) int32 per-slot chunk-start positions. Callers guarantee
-    ``W >= start + Sq`` for every row whose output is consumed. Returns
-    (B, Sq, Hq, hd) bf16."""
-    b, sq, hq, hd = q.shape
-    w, hkv = k.shape[1], k.shape[2]
-    g = hq // hkv
-    bq = min(bq, sq)
-    pq = (-sq) % bq                          # ragged chunk: padded query tail
-    if pq:                                   # rows are sliced off the output
-        q = jnp.pad(q, ((0, 0), (0, pq), (0, 0), (0, 0)))
-    n_q = (sq + pq) // bq
-    bk = min(bk, w)
-    k, v, k_s, v_s, n_kv = pad_kv_blocks(k, v, k_s, v_s, bk)
-    quantized = k_s is not None
-
-    inputs = [jnp.reshape(start, (b, 1)).astype(jnp.int32),
-              q.reshape(b, sq + pq, hkv, g, hd), k, v]
-    in_specs = [
-        pl.BlockSpec((1, 1), lambda bb, h, i, j: (bb, 0)),
-        pl.BlockSpec((1, bq, 1, g, hd), lambda bb, h, i, j: (bb, i, h, 0, 0)),
-        pl.BlockSpec((1, bk, 1, hd), lambda bb, h, i, j: (bb, j, h, 0)),
-        pl.BlockSpec((1, bk, 1, hd), lambda bb, h, i, j: (bb, j, h, 0)),
-    ]
-    if quantized:
-        inputs += list(transpose_scales(k_s, v_s))
-        in_specs += [pl.BlockSpec((1, 1, bk), lambda bb, h, i, j: (bb, h, j)),
-                     pl.BlockSpec((1, 1, bk), lambda bb, h, i, j: (bb, h, j))]
-
-    out = pl.pallas_call(
-        functools.partial(_kernel, bq=bq, bk=bk, g=g, n_kv=n_kv,
-                          scale=hd ** -0.5, quantized=quantized),
-        grid=(b, hkv, n_q, n_kv),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bq, 1, g, hd),
-                               lambda bb, h, i, j: (bb, i, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq + pq, hkv, g, hd),
-                                       jnp.bfloat16),
-        scratch_shapes=[pltpu.VMEM((bq * g,), jnp.float32),
-                        pltpu.VMEM((bq * g,), jnp.float32),
-                        pltpu.VMEM((bq * g, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-    )(*inputs)
-    out = out.reshape(b, sq + pq, hq, hd)
-    return out[:, :sq] if pq else out
+        for r in range(hq):
+            o_ref[0, :, r, :] = (acc_ref[r] / jnp.maximum(l_ref[r], 1e-30)
+                                 ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "interpret"))
@@ -183,15 +115,17 @@ def paged_prefill_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                                    start: jax.Array = None,
                                    pages: jax.Array = None, *, bq: int = 16,
                                    interpret: bool = False) -> jax.Array:
-    """Page-table-indirect chunked prefill: q (B, Sq, Hq, hd) vs a PAGED
-    arena (see ``paged_decode_attention_pallas`` for the layout). The KV
-    block size is pinned to ``page_size``; grid step (b, h, i, j) DMAs
-    physical page ``pages[b, j]`` via a scalar-prefetch index map. Ragged
-    query-tail padding is unchanged from the contiguous wrapper. Returns
-    (B, Sq, Hq, hd) bf16."""
+    """q (B, Sq, Hq, hd) at absolute positions start..start+Sq-1 vs a PAGED
+    arena: k/v (n_pages, page_size, Hkv, hd) float, uint16 (raw bf16 words)
+    or int8 (then k_s/v_s (n_pages, page_size, Hkv) f32 scales); start (B,)
+    int32; pages (B, n_blk) int32 — the window prefix of each row's page
+    table. The KV block size is pinned to ``page_size``; grid step (b, i, j)
+    DMAs physical page ``pages[b, j]``. Unallocated table entries point at
+    physical page 0 (the trash page) and sit beyond every causal limit.
+    Ragged chunks pad the query tail to a ``bq`` multiple; the padded rows
+    are sliced off. Returns (B, Sq, Hq, hd) bf16."""
     b, sq, hq, hd = q.shape
     ps, hkv = k.shape[1], k.shape[2]
-    g = hq // hkv
     bq = min(bq, sq)
     pq = (-sq) % bq                          # ragged chunk: padded query tail
     if pq:                                   # rows are sliced off the output
@@ -200,42 +134,64 @@ def paged_prefill_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     n_blk = pages.shape[1]
     quantized = k_s is not None
 
-    inputs = [q.reshape(b, sq + pq, hkv, g, hd), k, v]
+    def kv_page(bb, i, j, tbl, st):
+        # pages past the tile's deepest causal limit are skipped in the
+        # body; pinning their block index to the last visible page means
+        # the pipeline never fetches them either (an inactive row's start
+        # may be negative: the clamp keeps the table read in bounds)
+        last = (st[bb] + (i + 1) * bq - 1) // ps
+        return tbl[bb, jnp.clip(last, 0, j)]
+
+    inputs = [q, k, v]
     in_specs = [
-        pl.BlockSpec((1, bq, 1, g, hd),
-                     lambda bb, h, i, j, tbl, st: (bb, i, h, 0, 0)),
-        pl.BlockSpec((1, ps, 1, hd),
-                     lambda bb, h, i, j, tbl, st: (tbl[bb, j], 0, h, 0)),
-        pl.BlockSpec((1, ps, 1, hd),
-                     lambda bb, h, i, j, tbl, st: (tbl[bb, j], 0, h, 0)),
+        pl.BlockSpec((1, bq, hq, hd), lambda bb, i, j, tbl, st: (bb, i, 0, 0)),
+        pl.BlockSpec((1, ps, hkv, hd),
+                     lambda *a: (kv_page(*a), 0, 0, 0)),
+        pl.BlockSpec((1, ps, hkv, hd),
+                     lambda *a: (kv_page(*a), 0, 0, 0)),
     ]
     if quantized:
-        inputs += list(transpose_scales(k_s, v_s))   # (n_pages, Hkv, ps)
-        in_specs += [
-            pl.BlockSpec((1, 1, ps),
-                         lambda bb, h, i, j, tbl, st: (tbl[bb, j], h, 0))] * 2
+        inputs += [page_scales(k_s), page_scales(v_s)]
+        in_specs += [pl.BlockSpec((1, hkv, ps),
+                                  lambda *a: (kv_page(*a), 0, 0))] * 2
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, n_q, n_blk),
+        grid=(b, n_q, n_blk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bq, 1, g, hd),
-                               lambda bb, h, i, j, tbl, st: (bb, i, h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((bq * g,), jnp.float32),
-                        pltpu.VMEM((bq * g,), jnp.float32),
-                        pltpu.VMEM((bq * g, hd), jnp.float32)],
+        out_specs=pl.BlockSpec((1, bq, hq, hd),
+                               lambda bb, i, j, tbl, st: (bb, i, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((hq, bq, 1), jnp.float32),
+                        pltpu.VMEM((hq, bq, 1), jnp.float32),
+                        pltpu.VMEM((hq, bq, hd), jnp.float32)],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, bq=bq, bk=ps, g=g, n_kv=n_blk,
+        functools.partial(_kernel, bq=bq, bk=ps, g=hq // hkv, n_kv=n_blk,
                           scale=hd ** -0.5, quantized=quantized),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, sq + pq, hkv, g, hd),
-                                       jnp.bfloat16),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct((b, sq + pq, hq, hd), jnp.bfloat16),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(pages.astype(jnp.int32),
-      jnp.asarray(start, jnp.int32).reshape(b), *inputs)
-    out = out.reshape(b, sq + pq, hq, hd)
+    )(pages.astype(jnp.int32), jnp.asarray(start, jnp.int32).reshape(b),
+      *inputs)
     return out[:, :sq] if pq else out
+
+
+@functools.partial(jax.jit, static_argnames=("bq", "bk", "interpret"))
+def prefill_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
+                             k_s: Optional[jax.Array] = None,
+                             v_s: Optional[jax.Array] = None,
+                             start: jax.Array = None, *, bq: int = 16,
+                             bk: int = 16,
+                             interpret: bool = False) -> jax.Array:
+    """q: (B, Sq, Hq, hd) queries at absolute positions start..start+Sq-1;
+    k/v: (B, W, Hkv, hd) float or int8 (then k_s/v_s (B, W, Hkv) f32 scales);
+    start: (B,) int32 per-slot chunk-start positions. Callers guarantee
+    ``W >= start + Sq`` for every row whose output is consumed. The cache is
+    read as ``bk``-position pages through an identity table — with ``bk``
+    equal to a paged arena's page size (16 by default) the two layouts give
+    bit-identical results. Returns (B, Sq, Hq, hd) bf16."""
+    k, v, k_s, v_s, pages = as_pages(k, v, k_s, v_s, bk)
+    return paged_prefill_attention_pallas(q, k, v, k_s, v_s, start, pages,
+                                          bq=bq, interpret=interpret)

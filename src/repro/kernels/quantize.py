@@ -16,17 +16,18 @@ from jax.experimental import pallas as pl
 
 def _kernel(x_ref, q_ref, s_ref):
     x = x_ref[...].astype(jnp.float32)
-    amax = jnp.max(jnp.abs(x), axis=1)
-    scale = jnp.maximum(amax, 1e-8) / 127.0
-    q_ref[...] = jnp.clip(jnp.round(x / scale[:, None]), -127, 127
-                          ).astype(jnp.int8)
+    amax = jnp.max(jnp.abs(x), axis=1, keepdims=True)
+    scale = jnp.maximum(amax, 1e-8) / 127.0       # (bm, 1)
+    q_ref[...] = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
     s_ref[...] = scale
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
 def quantize_rowwise_pallas(x: jax.Array, *, bm: int = 256,
                             interpret: bool = False):
-    """x: (M, K) float -> ((M, K) int8, (M,) f32 scales)."""
+    """x: (M, K) float -> ((M, K) int8, (M,) f32 scales). The kernel
+    writes the scales as an (M, 1) column (see ``int8_matmul_pallas`` on
+    1-D blocks)."""
     m, k = x.shape
     bm = min(bm, m)
     pm = (-m) % bm
@@ -38,9 +39,9 @@ def quantize_rowwise_pallas(x: jax.Array, *, bm: int = 256,
         grid=(mp // bm,),
         in_specs=[pl.BlockSpec((bm, k), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((bm, k), lambda i: (i, 0)),
-                   pl.BlockSpec((bm,), lambda i: (i,))],
+                   pl.BlockSpec((bm, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((mp, k), jnp.int8),
-                   jax.ShapeDtypeStruct((mp,), jnp.float32)],
+                   jax.ShapeDtypeStruct((mp, 1), jnp.float32)],
         interpret=interpret,
     )(x)
-    return q[:m], s[:m]
+    return q[:m], s[:m, 0]

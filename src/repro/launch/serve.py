@@ -28,6 +28,8 @@ bit-identical to serial bf16 decode (``--verify`` checks exactly that).
 from __future__ import annotations
 
 import argparse
+import collections
+import contextlib
 import dataclasses
 import json
 import time
@@ -37,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import lm
 from repro.sharding.ctx import make_ctx
@@ -129,28 +132,26 @@ def _write_tracer(tracer, trace_dir, log):
         f"{jsonl_path}")
 
 
-def _start_profiler(profile_dir, log) -> bool:
-    """``--profile-dir``: wrap the engine run in ``jax.profiler.trace``.
-    Gated — some backends ship without profiler support, and a missing
-    profiler must degrade to a log line, not kill the serve."""
+def _profile(profile_dir):
+    """``--profile-dir``: a device profile around the engine run. A
+    profiler that cannot start or stop fails the run: a serve asked for a
+    profile must not finish without one."""
     if not profile_dir:
-        return False
-    try:
-        jax.profiler.start_trace(profile_dir)
-        return True
-    except Exception as e:                          # noqa: BLE001
-        log(f"[profile] jax.profiler unavailable ({e}); continuing without")
-        return False
+        return contextlib.nullcontext()
+    return jax.profiler.trace(profile_dir)
 
 
-def _stop_profiler(started: bool, profile_dir, log) -> None:
-    if not started:
-        return
-    try:
-        jax.profiler.stop_trace()
-        log(f"[profile] device profile written under {profile_dir}")
-    except Exception as e:                          # noqa: BLE001
-        log(f"[profile] stop_trace failed ({e})")
+def _fail_on_errors(eng, results, what: str) -> None:
+    """Request-scoped fault isolation finishes a faulted request with
+    ``finish_reason="error"`` and keeps serving; a launcher run that
+    produced one must still exit non-zero, with the engine's last fault
+    as the cause."""
+    bad = sorted(i for i, r in results.items() if r.finish_reason == "error")
+    if bad or eng.stats["faults"]:
+        raise SystemExit(
+            f"{what}: requests {bad} finished with an error "
+            f"({eng.stats['faults']} engine faults; last: "
+            f"{eng.last_fault!r})") from eng.last_fault
 
 
 def load_trace(path: str, cfg, seed: int = 0):
@@ -224,7 +225,8 @@ def serve_http(params, cfg, ctx, args, log=print, sampling=None, draft=None):
     from repro.serving.service import Service, ServiceConfig, run_http
     eng = build_engine(params, cfg, ctx, args, sampling=sampling, draft=draft)
     t0 = time.monotonic()
-    eng.run([Request(prompt=[3, 1, 4, 1, 5, 9], max_new_tokens=2)])
+    warm = eng.run([Request(prompt=[3, 1, 4, 1, 5, 9], max_new_tokens=2)])
+    _fail_on_errors(eng, warm, "[http] warmup")
     for k in eng.stats:
         eng.stats[k] = 0
     log(f"[http] warmup compile: {time.monotonic() - t0:.1f}s")
@@ -238,10 +240,9 @@ def serve_http(params, cfg, ctx, args, log=print, sampling=None, draft=None):
     # attach AFTER the warmup request so the trace starts at the first
     # client-visible submit
     tracer = _attach_tracer(eng, args.trace_dir)
-    prof = _start_profiler(args.profile_dir, log)
-    run_http(svc, host=args.host, port=args.port, log=log,
-             watchdog_s=args.watchdog_s or None)
-    _stop_profiler(prof, args.profile_dir, log)
+    with _profile(args.profile_dir):
+        run_http(svc, host=args.host, port=args.port, log=log,
+                 watchdog_s=args.watchdog_s or None)
     _write_tracer(tracer, args.trace_dir, log)
     return svc
 
@@ -268,15 +269,16 @@ def run_engine(params, cfg, ctx, args, log=print, sampling=None, draft=None):
 
     eng = build_engine(params, cfg, ctx, args, sampling=sampling, draft=draft)
     tracer = _attach_tracer(eng, args.trace_dir)
-    prof = _start_profiler(args.profile_dir, log)
-    t0 = time.monotonic()
-    results = eng.run(reqs, arrivals_s=arrivals)
-    wall = time.monotonic() - t0
-    _stop_profiler(prof, args.profile_dir, log)
+    with _profile(args.profile_dir):
+        t0 = time.monotonic()
+        results = eng.run(reqs, arrivals_s=arrivals)
+        wall = time.monotonic() - t0
     _write_tracer(tracer, args.trace_dir, log)
 
     stats = {
         **summarize_results(results, wall),
+        "finish_reasons": dict(collections.Counter(
+            r.finish_reason for r in results.values())),
         "n_slots": args.engine_slots,
         "prefill_chunk": args.prefill_chunk,
         **eng.stats,
@@ -297,6 +299,7 @@ def run_engine(params, cfg, ctx, args, log=print, sampling=None, draft=None):
         + (f", {eng.stats['prefix_hits']} prefix hits / "
            f"{eng.stats['pages_peak']} pages peak" if args.page_size else "")
         + ")")
+    _fail_on_errors(eng, results, "[engine]")
 
     verify = args.verify if args.verify is not None else args.smoke
     if verify and draft is not None and sampling is not None \
@@ -306,24 +309,31 @@ def run_engine(params, cfg, ctx, args, log=print, sampling=None, draft=None):
             "speculative mode is token-identical and verifiable)")
         verify = False
     if verify:
-        bad = []
+        bad = {}
         for i, res in sorted(results.items()):
             req = reqs[i]
             ref = serial_decode(params, cfg, req.prompt, req.max_new_tokens,
                                 ctx=ctx, max_seq=args.max_seq,
-                                eos_id=req.eos_id, sampling=sampling)
+                                eos_id=req.eos_id, sampling=sampling,
+                                decode_rows=args.engine_slots)
             if res.tokens != ref:
-                bad.append(i)
+                bad[i] = next((t for t, (a, b) in enumerate(
+                    zip(res.tokens, ref)) if a != b),
+                    min(len(res.tokens), len(ref)))
         if bad:
-            raise SystemExit(f"[engine] VERIFY FAILED: requests {bad} differ "
-                             f"from serial single-request decode")
+            raise SystemExit(f"[engine] VERIFY FAILED: requests {sorted(bad)} "
+                             f"differ from serial single-request decode "
+                             f"(first differing token index by request: "
+                             f"{bad})")
         log(f"[engine] verify: all {len(results)} outputs token-identical "
             f"to serial decode")
     return results, stats
 
 
 # -------------------------------------------------------------------- main
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
+    """The serve command line (``main`` parses it; ``chip_smoke.py`` reuses
+    it so its in-process front door serves the same flags)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--smoke", action="store_true")
@@ -381,9 +391,9 @@ def main(argv=None):
                          "(engine mode, trace replay or --http)")
     ap.add_argument("--profile-dir", default=None,
                     help="wrap the engine run in jax.profiler.trace and "
-                         "write the device profile here (engine mode; "
-                         "degrades to a log line if the backend has no "
-                         "profiler)")
+                         "write the device profile here (engine mode; a "
+                         "profiler that fails to start or stop fails the "
+                         "run)")
     ap.add_argument("--http", action="store_true",
                     help="serve over HTTP with SSE token streaming instead "
                          "of replaying a trace (implies --engine; blocks "
@@ -413,6 +423,11 @@ def main(argv=None):
     ap.add_argument("--verify", action="store_true", default=None,
                     help="check engine outputs == serial decode "
                          "(default: on under --smoke)")
+    return ap
+
+
+def main(argv=None):
+    ap = build_parser()
     args = ap.parse_args(argv)
 
     if args.http:
@@ -440,6 +455,7 @@ def main(argv=None):
             ap.error("--spec-k needs a drafter: pass --hqp (build one) or "
                      "--load-artifact")
 
+    use_compile_cache()
     cfg = (configs.get_smoke_config(args.arch) if args.smoke
            else configs.get_config(args.arch))
     mesh = make_host_mesh()

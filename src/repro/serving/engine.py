@@ -44,6 +44,9 @@ absolute positions — so chunk boundaries, query-tile sizes, and window
 buckets all yield bit-identical logits (out-of-window/limit positions
 contribute exact zeros) — and (c) inactive/stopped slots are select-masked
 back to their pre-step state after every batched decode step, on device.
+XLA on TPU is not batch-invariant (a one-row program can round differently
+from an ``n_slots``-row one), so the serial reference decodes at the
+engine's slot width (``serial_decode(decode_rows=n_slots)``).
 
 Beyond greedy lockstep, the engine carries two optional modes (both
 preserving the identity contract in their greedy forms): seeded
@@ -312,6 +315,9 @@ class Engine:
         # before each fallible phase so _absorb_fault knows the blast
         # radius of whatever raised
         self._fault_phase = None
+        # the exception behind the most recent absorbed fault (None until
+        # one happens): isolation keeps serving, launchers report the cause
+        self.last_fault: Optional[BaseException] = None
 
         cfg_, ctx_ = self.cfg, self.ctx
         paged = self.paged
@@ -756,7 +762,8 @@ class Engine:
             out = self._step_inner()
         except AssertionError:
             raise
-        except Exception:
+        except Exception as e:
+            self.last_fault = e
             out = self._absorb_fault()
         wall = self.clock() - t0
         self._ph["total"] = wall
@@ -1206,11 +1213,19 @@ def _serial_sampler(scfg: smp.SamplingConfig):
 def serial_decode(params, cfg, prompt: Sequence[int], max_new_tokens: int,
                   ctx: Optional[RunContext] = None, max_seq: int = 128,
                   eos_id: Optional[int] = None,
-                  sampling: Optional[smp.SamplingConfig] = None) -> List[int]:
+                  sampling: Optional[smp.SamplingConfig] = None,
+                  decode_rows: int = 1) -> List[int]:
     """The serial single-request path the engine must match token-for-token:
     whole-prompt prefill, then one decode step per token. Greedy by default;
     a non-greedy ``sampling`` draws each token with the shared
-    position-derived key rule."""
+    position-derived key rule.
+
+    ``decode_rows``: the decode steps run the request replicated over this
+    many batch rows and read row 0. XLA on TPU is not batch-invariant — a
+    one-row program can round differently from a four-row one (its fusions
+    differ) — so the reference for an engine with ``n_slots`` slots decodes
+    at that width, exactly as the engine's batched decode does (prefill is
+    one row in both)."""
     ctx = ctx or default_ctx()
     scfg = sampling or smp.GREEDY
     prompt = np.asarray(prompt, np.int32)
@@ -1222,6 +1237,10 @@ def serial_decode(params, cfg, prompt: Sequence[int], max_new_tokens: int,
         return _pick_token(logits_row, pos, sampler)
 
     logits, state = step(params, state, jnp.asarray(prompt[None]))
+    if decode_rows > 1:     # cache leaves carry the batch on axis 1
+        state = {"caches": jax.tree.map(
+            lambda t: jnp.repeat(t, decode_rows, axis=1), state["caches"]),
+            "pos": state["pos"]}
     out: List[int] = []
     tok = pick(logits[0, -1], int(prompt.size))
     while True:
@@ -1229,5 +1248,5 @@ def serial_decode(params, cfg, prompt: Sequence[int], max_new_tokens: int,
         if tok == eos_id or len(out) >= max_new_tokens:
             return out
         logits, state = step(params, state,
-                             jnp.full((1, 1), tok, jnp.int32))
+                             jnp.full((decode_rows, 1), tok, jnp.int32))
         tok = pick(logits[0, -1], int(prompt.size) + len(out))

@@ -139,6 +139,77 @@ def test_prefill_kernel_chunk_invariant_bitwise(quantized):
                                   np.asarray(narrow, np.float32))
 
 
+def _arena(key, quantized, n_pages, ps):
+    """A paged arena as the engine stores it: uint16 words for bf16 KV."""
+    from repro.kernels.kv_layout import to_store
+    arena = _cache(key, ps, quantized)        # (B, ps, ...) -> re-lead
+    arena = {k: jnp.concatenate([v] * (n_pages // B + 1))[:n_pages]
+             for k, v in arena.items()}
+    if not quantized:
+        arena = {k: to_store(v, jnp.uint16) for k, v in arena.items()}
+    return arena
+
+
+@pytest.mark.parametrize("sq", [1, 5, 16])
+@pytest.mark.parametrize("quantized", [False, True])
+def test_paged_kernel_ref_vs_einsum(quantized, sq):
+    """The paged kernel (interpret mode) against the xla paged oracle
+    (gather + einsum) through a shuffled page table: the scalar-prefetch
+    index maps, the clamped past-the-limit page index, and uint16 words
+    bitcast back to bf16 inside the kernel."""
+    from repro.kernels.kv_layout import from_store
+    from repro.kernels.ref import paged_prefill_attention_ref
+    ps, n_blk = 8, 6
+    key = jax.random.PRNGKey(sq + 17 * quantized)
+    arena = _arena(key, quantized, 1 + B * n_blk, ps)
+    pages = jnp.asarray(1 + np.random.RandomState(sq).permutation(
+        B * n_blk).reshape(B, n_blk), jnp.int32)
+    q = jax.random.normal(jax.random.fold_in(key, 5), (B, sq, HQ, HD),
+                          jnp.bfloat16)
+    start = jnp.asarray([0, 13, ps * n_blk - sq], jnp.int32)
+    args = _kernel_args(arena)
+    out = get_backend("ref").prefill_attention_paged(
+        q, *args, start, pages)
+    oracle = paged_prefill_attention_ref(
+        q, *(a if a is None or quantized else from_store(a) for a in args),
+        start, pages)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(oracle, np.float32),
+                               rtol=3e-2, atol=1.5e-1 if quantized else 3e-2)
+
+
+@pytest.mark.parametrize("sq", [1, 7])
+@pytest.mark.parametrize("quantized", [False, True])
+def test_contiguous_kernel_bitwise_equals_paged(quantized, sq):
+    """A contiguous cache read in ``bk`` blocks and the same KV in a paged
+    arena of ``bk``-position pages give bit-identical results: the online
+    softmax folds the same blocks in the same order. On the chip this is
+    what keeps a paged engine token-identical to serial (contiguous)
+    decode."""
+    from repro.kernels.kv_layout import as_pages
+    from repro.kernels.prefill_attention import paged_prefill_attention_pallas
+    max_seq, bk = 48, 16
+    key = jax.random.PRNGKey(sq)
+    cache = _cache(key, max_seq, quantized)
+    q = jax.random.normal(jax.random.fold_in(key, 6), (B, sq, HQ, HD),
+                          jnp.bfloat16)
+    start = jnp.asarray([0, 20, max_seq - sq], jnp.int32)
+    contiguous = prefill_attention_pallas(q, *_kernel_args(cache), start,
+                                          bk=bk, interpret=True)
+    k, v, k_s, v_s, ident = as_pages(*_kernel_args(cache), bk)
+    # the same pages in reverse physical order, behind a trash page
+    n = k.shape[0]
+    perm = np.arange(n)[::-1]
+    lift = lambda t: None if t is None else jnp.concatenate(
+        [jnp.zeros_like(t[:1]), t[perm]])
+    table = 1 + jnp.asarray(np.argsort(perm))[ident]
+    paged = paged_prefill_attention_pallas(q, lift(k), lift(v), lift(k_s),
+                                           lift(v_s), start, table,
+                                           interpret=True)
+    np.testing.assert_array_equal(np.asarray(contiguous, np.float32),
+                                  np.asarray(paged, np.float32))
+
+
 def test_prefill_attention_registered_on_all_backends():
     """Every registered backend exposes the prefill primitive; every backend
     executable on this platform produces a finite, well-shaped result

@@ -101,9 +101,13 @@ def test_interleaved_prefill_decode_token_identical(setup):
         for res in eng.step():
             results[res.uid] = res
     assert eng.stats["decode_ticks"] > 0 and eng.stats["prefill_ticks"] >= 3
+    # the reference decodes one row, or the engine's slot width (the form
+    # serve --verify uses; equal wherever XLA is batch-invariant)
     for uid, prompt in zip(uids, prompts):
-        ref = serial_decode(params, cfg, prompt, 6, max_seq=64)
-        assert results[uid].tokens == ref, (uid, results[uid].tokens, ref)
+        for rows in (1, eng.n_slots):
+            ref = serial_decode(params, cfg, prompt, 6, max_seq=64,
+                                decode_rows=rows)
+            assert results[uid].tokens == ref, (uid, rows, ref)
 
 
 def test_engine_matches_decode_step_on_artifact(setup):
@@ -141,7 +145,9 @@ def test_xlstm_engine_matches_serial_token_identical():
     res = eng.run([Request(prompt=p, max_new_tokens=6) for p in prompts],
                   arrival_ticks=[0, 2, 4])
     for idx, prompt in enumerate(prompts):
-        ref = serial_decode(params, cfg, prompt, 6, max_seq=64)
+        # recurrent leaves carry the batch on axis 1 too (decode_rows)
+        ref = serial_decode(params, cfg, prompt, 6, max_seq=64,
+                            decode_rows=eng.n_slots)
         assert res[idx].tokens == ref, (idx, res[idx].tokens, ref)
 
 
@@ -331,3 +337,84 @@ def test_serve_engine_trace_replay(setup, tmp_path):
     assert stats["n_requests"] == 3
     assert stats["out_tokens"] == 12
     assert stats["tokens_per_s"] > 0
+
+
+def _faulty_build_engine(monkeypatch, serve):
+    """serve's engines fail their first decode dispatch."""
+    from repro.serving import faults
+    real = serve.build_engine
+
+    def build(*a, **k):
+        eng = real(*a, **k)
+        faults.inject_decode_fault(eng, at=1)
+        return eng
+    monkeypatch.setattr(serve, "build_engine", build)
+    return faults.InjectedFault
+
+
+def test_serve_engine_exits_nonzero_on_errored_request(setup, tmp_path,
+                                                       monkeypatch):
+    """Fault isolation keeps the engine serving, but a trace replay in
+    which a request finished ``"error"`` is a failed run, with the fault
+    as its cause."""
+    import json
+    from repro.launch import serve
+    fault = _faulty_build_engine(monkeypatch, serve)
+    trace = tmp_path / "trace.jsonl"
+    trace.write_text(json.dumps({"prompt_len": 6, "max_new_tokens": 4}))
+    with pytest.raises(SystemExit, match="finished with an error") as e:
+        serve.main(["--smoke", "--engine", "--trace", str(trace),
+                    "--max-seq", "32"])
+    assert isinstance(e.value.__cause__, fault)
+
+
+def test_serve_http_warmup_fault_exits_before_listening(setup, monkeypatch):
+    """The HTTP warmup checks its own result before zeroing the stats: a
+    warmup fault fails the server instead of vanishing."""
+    from repro.launch import serve
+    _faulty_build_engine(monkeypatch, serve)
+
+    def never(*a, **k):
+        raise AssertionError("the listener opened after a failed warmup")
+    monkeypatch.setattr("repro.serving.service.run_http", never)
+    with pytest.raises(SystemExit, match="warmup"):
+        serve.main(["--smoke", "--http", "--port", "0", "--max-seq", "32"])
+
+
+def test_serve_profiler_failure_is_fatal(setup, tmp_path, monkeypatch):
+    """``--profile-dir`` with a profiler that cannot start fails the run."""
+    import json
+    from repro.launch import serve
+
+    def broken(*a, **k):
+        raise RuntimeError("profiler unavailable")
+    monkeypatch.setattr(jax.profiler, "trace", broken)
+    trace = tmp_path / "trace.jsonl"
+    trace.write_text(json.dumps({"prompt_len": 6, "max_new_tokens": 2}))
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        serve.main(["--smoke", "--engine", "--trace", str(trace),
+                    "--max-seq", "32", "--profile-dir",
+                    str(tmp_path / "prof")])
+
+
+def test_compile_cache_dir_rule(monkeypatch):
+    """The persistent compile cache goes where ``JAX_COMPILATION_CACHE_DIR``
+    says, with no other directory set in code; without it, to one fixed,
+    git-ignored directory inside the checkout."""
+    import pathlib
+    from repro.launch.compile_cache import CHECKOUT_CACHE_DIR, use_compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert use_compile_cache() == use_compile_cache() \
+            == str(CHECKOUT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(CHECKOUT_CACHE_DIR)
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert CHECKOUT_CACHE_DIR.parent == root
+        assert f"{CHECKOUT_CACHE_DIR.name}/" in (root / ".gitignore").read_text()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -639,18 +639,13 @@ async def _sse_request(port: int, body: bytes, delay_s: float = 0.0) -> dict:
     return rec
 
 
-def _run_http_phase(eng, queue_depth, deadline_s, bodies, delays,
-                    telemetry_on: bool = True):
+def _run_http_phase(eng, queue_depth, deadline_s, bodies, delays):
     """Fresh Service + front door (ephemeral port) on an already-compiled
     engine; fire one client per body at its delay; drain; return
-    (svc.stats, client records, wall_s measured send-to-last-done).
-    ``telemetry_on=False`` builds the service without the metrics
-    registry/histograms — the control arm of the telemetry-overhead
-    gate."""
+    (svc.stats, client records, wall_s measured send-to-last-done)."""
     from repro.serving.service import HttpFrontDoor, Service, ServiceConfig
     svc = Service(eng, ServiceConfig(queue_depth=queue_depth,
-                                     default_deadline_s=deadline_s,
-                                     telemetry=telemetry_on))
+                                     default_deadline_s=deadline_s))
     door = HttpFrontDoor(svc, host="127.0.0.1", port=0)
     # the previous phase's garbage (dead Service/door/streams, the inproc
     # run's result objects) must not bill its collector pauses to THIS
@@ -757,17 +752,21 @@ def bench_http(out_path: str = "BENCH_serving.json") -> List[Row]:
     rows: List[Row] = []
 
     # --- closed loop: all clients at once, queue deep enough to admit all.
-    # The in-process baseline, the HTTP phase, and the telemetry-off HTTP
-    # control run INTERLEAVED, best-of each, so CPU-clock drift between
-    # measurement windows cancels out of goodput_ratio instead of
-    # masquerading as overhead. Five iterations, not three: on a one-core
-    # box the per-phase wall jitters ~+/-4% (scheduler bursts slow an
-    # entire iteration — its inproc AND http phases together), and the
-    # best-of floor estimator needs enough samples for both arms' minima
-    # to converge or the 0.9x goodput gate flakes on noise alone.
-    in_best = best = off_best = None
-    pair_ratios = []
-    for it in range(7):
+    # The in-process baseline and the HTTP phase run INTERLEAVED, best-of
+    # each, so CPU-clock drift between measurement windows cancels out of
+    # goodput_ratio instead of masquerading as overhead. Seven iterations:
+    # on a one-core box the per-phase wall jitters ~+/-4% (scheduler
+    # bursts slow an entire iteration — its inproc AND http phases
+    # together), and the best-of floor estimator needs enough samples for
+    # both arms' minima to converge or the 0.9x goodput gate flakes on
+    # noise alone. goodput_ratio is best-of/best-of, not paired: its two
+    # arms respond to scheduler noise ASYMMETRICALLY (the http arm's
+    # thread ping-pong amplifies contention the inproc run shrugs off),
+    # so pairing would fold that asymmetry in as phantom transport cost,
+    # while the minima compare both arms at the box's capable state —
+    # which is what a transport-cost floor means.
+    in_best = best = None
+    for _ in range(7):
         for k in eng.stats:
             eng.stats[k] = 0
         # drain garbage left by earlier benches / the previous iteration
@@ -780,42 +779,13 @@ def bench_http(out_path: str = "BENCH_serving.json") -> List[Row]:
         iwall = time.perf_counter() - t0
         if in_best is None or iwall < in_best[1]:
             in_best = (results, iwall)
-        # the on/off order ALTERNATES per iteration: the box drifts on
-        # ~second scales (GC debt from the preceding phase, scheduler
-        # bursts), and a fixed order would fold that drift into the
-        # overhead ratio as a systematic bias instead of noise
-        hwall = owall = None
-        for tel_on in ((True, False) if it % 2 == 0 else (False, True)):
-            if tel_on:
-                st, recs, hwall = _run_http_phase(
-                    eng, queue_depth=n_req, deadline_s=None, bodies=bodies,
-                    delays=[0.0] * n_req)
-                if best is None or hwall < best[2]:
-                    best = (st, recs, hwall)
-            else:
-                _, orecs, owall = _run_http_phase(
-                    eng, queue_depth=n_req, deadline_s=None, bodies=bodies,
-                    delays=[0.0] * n_req, telemetry_on=False)
-                if off_best is None or owall < off_best[1]:
-                    off_best = (orecs, owall)
-        # the overhead ratio is PAIRED per iteration (on-phase wall vs the
-        # adjacent off-phase wall, same token count, both http) and
-        # summarized by the median: noise bursts hit adjacent phases
-        # together and cancel inside the pair, so a <=3% effect stays
-        # resolvable. goodput_ratio stays best-of/best-of instead: its two
-        # arms respond to scheduler noise ASYMMETRICALLY (the http arm's
-        # thread ping-pong amplifies contention the inproc run shrugs
-        # off), so pairing folds that asymmetry in as phantom transport
-        # cost, while the minima compare both arms at the box's capable
-        # state — which is what a transport-cost floor means
-        pair_ratios.append(owall / max(hwall, 1e-9))
+        st, recs, hwall = _run_http_phase(
+            eng, queue_depth=n_req, deadline_s=None, bodies=bodies,
+            delays=[0.0] * n_req)
+        if best is None or hwall < best[2]:
+            best = (st, recs, hwall)
     inproc = summarize_results(*in_best)
     st, recs, hwall = best
-    orecs, owall = off_best
-    off_tokens = sum(r["n_tokens"] for r in orecs
-                     if r["finish_reason"] in ("length", "eos"))
-    off_goodput = off_tokens / max(owall, 1e-9)
-    overhead_ratio = float(np.median(pair_ratios))
     done = [r for r in recs if r["finish_reason"] in ("length", "eos")]
     out_tokens = sum(r["n_tokens"] for r in done)
     ttfts = [r["token_times"][0] - r["t_send"] for r in done
@@ -838,8 +808,6 @@ def bench_http(out_path: str = "BENCH_serving.json") -> List[Row]:
         "max_new_tokens": new_tok,
         "inproc_tokens_per_s": inproc["tokens_per_s"],
         "goodput_ratio": goodput / max(inproc["tokens_per_s"], 1e-9),
-        "tokens_per_s_telemetry_off": off_goodput,
-        "telemetry_overhead_ratio": overhead_ratio,
         "ttft_hist": _client_hist(ttfts),
         "latency_hist": _client_hist(lats),
         "completed": len(done),
@@ -852,8 +820,7 @@ def bench_http(out_path: str = "BENCH_serving.json") -> List[Row]:
         "serving/http_stream", hwall / max(out_tokens, 1) * 1e6,
         f"goodput={goodput:.1f}tok_s ({v['goodput_ratio']:.2f}x inproc) "
         f"ttft_p50={v['ttft_p50_ms']:.1f}ms "
-        f"gap_p50={v['tok_gap_p50_ms']:.1f}ms shed={st['shed']} "
-        f"telemetry_overhead={v['telemetry_overhead_ratio']:.3f}x"))
+        f"gap_p50={v['tok_gap_p50_ms']:.1f}ms shed={st['shed']}"))
 
     # --- open loop: uniform arrivals swept past the knee, shallow queue
     cap_rps = inproc["tokens_per_s"] / new_tok

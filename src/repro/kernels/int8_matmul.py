@@ -76,5 +76,7 @@ def int8_matmul_pallas(x_q: jax.Array, w_q: jax.Array, x_scale: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        # the kernel's name, and the name of its operation in a profile
+        name="int8_matmul_pallas",
     )(x_q, w_q, x_scale, w_scale)
     return out[:m, :n]

@@ -173,6 +173,8 @@ def paged_prefill_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        # the kernel's name, and the name of its operation in a profile
+        name="paged_prefill_attention_pallas",
     )(pages.astype(jnp.int32), jnp.asarray(start, jnp.int32).reshape(b),
       *inputs)
     return out[:, :sq] if pq else out

@@ -43,5 +43,7 @@ def quantize_rowwise_pallas(x: jax.Array, *, bm: int = 256,
         out_shape=[jax.ShapeDtypeStruct((mp, k), jnp.int8),
                    jax.ShapeDtypeStruct((mp, 1), jnp.float32)],
         interpret=interpret,
+        # the kernel's name, and the name of its operation in a profile
+        name="quantize_rowwise_pallas",
     )(x)
     return q[:m], s[:m, 0]

@@ -174,33 +174,35 @@ def update_kv_cache(cache: dict, k_new: jax.Array, v_new: jax.Array,
     values (INT8 quant included — it is per-(pos, head)) are identical to
     the contiguous write, which is what keeps prefix-cache page reuse
     bit-exact across requests."""
-    if "k_q" in cache:
-        kq, ks = _quant_kv(k_new)
-        vq, vs = _quant_kv(v_new)
-        new = {"k_q": kq, "v_q": vq, "k_s": ks, "v_s": vs}
-    else:
-        # bf16 caches store raw uint16 words (init_kv_cache) —
-        # scatter_pages bitcasts the update itself, so keep it in compute
-        # dtype there; the contiguous DUS paths bitcast here
-        new = {"k": k_new.astype(L.COMPUTE_DTYPE),
-               "v": v_new.astype(L.COMPUTE_DTYPE)}
-    if pages is not None:
-        from repro.kernels.kv_layout import scatter_pages
-        return {key: scatter_pages(cache[key], new[key], pages, pos)
-                for key in cache}
-    from repro.kernels.kv_layout import to_store
-    new = {key: to_store(val, cache[key].dtype) for key, val in new.items()}
-    if jnp.ndim(pos) == 0:
-        def scatter(buf, upd):
-            idx = (0, pos) + (0,) * (buf.ndim - 2)
-            return jax.lax.dynamic_update_slice(buf, upd, idx)
-    else:
-        def scatter(buf, upd):
-            def row(b_row, u_row, p):
-                idx = (p,) + (0,) * (b_row.ndim - 1)
-                return jax.lax.dynamic_update_slice(b_row, u_row, idx)
-            return jax.vmap(row)(buf, upd, pos)
-    return {key: scatter(cache[key], new[key]) for key in cache}
+    with jax.named_scope("kv_write"):
+        if "k_q" in cache:
+            kq, ks = _quant_kv(k_new)
+            vq, vs = _quant_kv(v_new)
+            new = {"k_q": kq, "v_q": vq, "k_s": ks, "v_s": vs}
+        else:
+            # bf16 caches store raw uint16 words (init_kv_cache) —
+            # scatter_pages bitcasts the update itself, so keep it in
+            # compute dtype there; the contiguous DUS paths bitcast here
+            new = {"k": k_new.astype(L.COMPUTE_DTYPE),
+                   "v": v_new.astype(L.COMPUTE_DTYPE)}
+        if pages is not None:
+            from repro.kernels.kv_layout import scatter_pages
+            return {key: scatter_pages(cache[key], new[key], pages, pos)
+                    for key in cache}
+        from repro.kernels.kv_layout import to_store
+        new = {key: to_store(val, cache[key].dtype)
+               for key, val in new.items()}
+        if jnp.ndim(pos) == 0:
+            def scatter(buf, upd):
+                idx = (0, pos) + (0,) * (buf.ndim - 2)
+                return jax.lax.dynamic_update_slice(buf, upd, idx)
+        else:
+            def scatter(buf, upd):
+                def row(b_row, u_row, p):
+                    idx = (p,) + (0,) * (b_row.ndim - 1)
+                    return jax.lax.dynamic_update_slice(b_row, u_row, idx)
+                return jax.vmap(row)(buf, upd, pos)
+        return {key: scatter(cache[key], new[key]) for key in cache}
 
 
 def cached_attention(q: jax.Array, cache: dict, start: jax.Array,

@@ -110,15 +110,16 @@ def init_params(key, cfg) -> dict:
 def _ffn_part(p: dict, cfg, x, is_moe: bool, ctx, with_aux: bool):
     if cfg.d_ff <= 0:
         return x, {}
-    h = L.rmsnorm(x, p["norm2"], cfg.norm_eps)
-    aux = {}
-    if is_moe:
-        out, aux = M.moe_forward(p["moe"], cfg, h, ctx, with_aux)
-        if cfg.moe.dense_residual:
-            out = out + L.mlp(h, p["mlp"])
-    else:
-        out = L.mlp(h, p["mlp"])
-    return x + out, aux
+    with jax.named_scope("mlp"):
+        h = L.rmsnorm(x, p["norm2"], cfg.norm_eps)
+        aux = {}
+        if is_moe:
+            out, aux = M.moe_forward(p["moe"], cfg, h, ctx, with_aux)
+            if cfg.moe.dense_residual:
+                out = out + L.mlp(h, p["mlp"])
+        else:
+            out = L.mlp(h, p["mlp"])
+        return x + out, aux
 
 
 def _block_forward(kind: str, is_moe: bool, p: dict, cfg, x, positions, ctx,
@@ -127,9 +128,10 @@ def _block_forward(kind: str, is_moe: bool, p: dict, cfg, x, positions, ctx,
     h = L.rmsnorm(x, p["norm1"], cfg.norm_eps)
     new_cache = cache
     if kind == "attn":
-        a, new_cache = A.attention_forward(p["attn"], cfg, h, positions,
-                                           cache, cur_len, ctx, window,
-                                           route, pages)
+        with jax.named_scope("attention"):
+            a, new_cache = A.attention_forward(p["attn"], cfg, h, positions,
+                                               cache, cur_len, ctx, window,
+                                               route, pages)
         x = x + a
         x, aux = _ffn_part(p, cfg, x, is_moe, ctx, with_aux)
     elif kind == "mamba":
@@ -383,7 +385,8 @@ def decode_step(params: dict, cfg, state: dict, tokens: jax.Array,
     x, new_caches = jax.lax.scan(group, x,
                                  (params["blocks"], state["caches"]))
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = logits_fn(params, cfg, x)
+    with jax.named_scope("logits"):
+        logits = logits_fn(params, cfg, x)
     return logits, {"caches": new_caches, "pos": cur + s}
 
 

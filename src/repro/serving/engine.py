@@ -23,6 +23,16 @@ pool is built on ``init_decode_state(..., params=...)``: HQP-compacted
 artifacts size their own caches, and ``QuantizedLinear`` weights dispatch
 through the kernels/backend registry exactly as on the serial path.
 
+Each callable jits a function of a stable name (``engine_reset``,
+``engine_prefill``, ``engine_decode``, ``engine_spec_prefill``,
+``engine_copy_page``), so a profile names each executable's device work
+(``jit_engine_decode``...); inside them ``jax.named_scope`` marks the model
+step's parts (``kv_*`` slot and cache ops, ``attention``, ``mlp``,
+``logits``, ``sample``). Every tick is a ``repro.engine.step`` profiler
+step annotation, and each of its phases (``telemetry.schema.PHASES``) a
+``repro.engine.<phase>`` annotation carrying ``t_engine``, the engine-clock
+reading at which it opened (``_Phase``).
+
 Two length-aware fast paths (DESIGN.md §10):
 
   * every KV attend carries a STATIC ``window`` — the live sequence bound
@@ -145,6 +155,56 @@ def _kv_bytes(pool) -> int:
     return sum(leaf.nbytes
                for entry in pool["caches"] if sp.is_kv_entry(entry)
                for leaf in jax.tree_util.tree_leaves(entry))
+
+
+class Annotation:
+    """``with Annotation(label, clock, **args) as a:`` holds the profiler
+    annotation ``label`` over the block, tagged with ``args`` and with
+    ``t_engine``: ``clock``'s reading (kept as ``a.t0``), taken just after
+    the annotation opened, so that the trace's start of the annotation and
+    the reading lie as close together as Python allows and a profile can
+    be put on the program's clock. A ``step_num`` argument makes it a step
+    annotation."""
+    __slots__ = ("label", "clock", "args", "t0", "ann")
+
+    def __init__(self, label: str, clock, **args):
+        self.label, self.clock, self.args = label, clock, args
+
+    def __enter__(self) -> "Annotation":
+        step = self.args.pop("step_num", None)
+        self.ann = (jax.profiler.TraceAnnotation(self.label) if step is None
+                    else jax.profiler.StepTraceAnnotation(self.label,
+                                                          step_num=step))
+        self.ann.__enter__()
+        self.t0 = self.clock()
+        self.ann.set_metadata(t_engine=self.t0, **self.args)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.ann.__exit__(*exc)
+        return False
+
+
+class _Phase(Annotation):
+    """One timed phase of an engine tick, as the annotation
+    ``repro.engine.<name>``: its two readings of the engine's injected
+    clock, on entry (``t0``) and on a clean exit (``t1``), write their
+    difference to ``eng._ph[name]`` (a phase that raises records
+    nothing)."""
+    __slots__ = ("ph", "name", "t1")
+
+    def __init__(self, eng: "Engine", name: str, label: str = "", **args):
+        """``label`` names the annotation where it is not the phase's
+        key."""
+        super().__init__("repro.engine." + (label or name), eng.clock,
+                         **args)
+        self.ph, self.name = eng._ph, name
+
+    def __exit__(self, *exc) -> bool:
+        if exc[0] is None:
+            self.t1 = self.clock()
+            self.ph[self.name] = self.t1 - self.t0
+        return super().__exit__(*exc)
 
 
 def _pick_token(logits_row, pos: int, sampler) -> int:
@@ -330,10 +390,10 @@ class Engine:
             return jax.lax.dynamic_slice(table, (slot, 0),
                                          (1, table.shape[1]))
 
-        def _reset(pool, slot, template, pos0):
+        def engine_reset(pool, slot, template, pos0):
             return sp.reset_slot(pool, slot, template, pos0, paged)
 
-        def _prefill(params, pool, table, slot, chunk, window):
+        def engine_prefill(params, pool, table, slot, chunk, window):
             st = sp.gather_slot(pool, slot, paged)
             if paged:
                 st = dict(st, pages=_row(table, slot))
@@ -347,8 +407,8 @@ class Engine:
                                          window=window, route="prefill")
             return logits[:, -1], sp.scatter_slot(pool, slot, new, paged)
 
-        def _spec_prefill(dparams, vparams, dpool, vpool, table, slot,
-                          chunk, window):
+        def engine_spec_prefill(dparams, vparams, dpool, vpool, table, slot,
+                                chunk, window):
             # speculative mode prefills BOTH pools from one dispatch (the
             # drafter's chunk logits are never consumed — the first token
             # always comes from the verifier); fusing halves the per-chunk
@@ -364,8 +424,8 @@ class Engine:
                     sp.scatter_slot(dpool, slot, dnew, paged),
                     sp.scatter_slot(vpool, slot, vnew, paged))
 
-        def _decode(params, pool, table, tokens, active, eos, budget,
-                    window):
+        def engine_decode(params, pool, table, tokens, active, eos, budget,
+                          window):
             """``decode_steps`` greedy steps on device. tokens (B, 1) i32 =
             each live slot's last emitted token; active (B,) bool; eos (B,)
             i32 (-1 = no EOS id); budget (B,) i32 = tokens the slot may
@@ -383,8 +443,9 @@ class Engine:
                 # reproduces serial sampling token-for-token per seed;
                 # greedy is a static argmax branch (no keys, bit-identical
                 # to the pre-sampling engine)
-                nxt = smp.sample_batch(logits[:, -1], scfg, base_key,
-                                       new["pos"])
+                with jax.named_scope("sample"):
+                    nxt = smp.sample_batch(logits[:, -1], scfg, base_key,
+                                           new["pos"])
                 pool = sp.select_slots(new, pool, live, paged)
                 left = jnp.where(live, left - 1, left)
                 stop = ((eos >= 0) & (nxt == eos)) | (left <= 0)
@@ -397,7 +458,7 @@ class Engine:
                 length=decode_steps)
             return toks, emitted, pool
 
-        def _copy_page(pool, dpool, src, dst):
+        def engine_copy_page(pool, dpool, src, dst):
             # copy-on-write: duplicate arena page src -> dst in every KV
             # entry of both pools (dpool is None outside speculative mode;
             # the page axis of an arena leaf is axis 1, under the group
@@ -426,28 +487,34 @@ class Engine:
             "engine.reset", host_syncs=1, donated=("pool",),
             forbid_f32_roundtrip_on=("kv",),
             max_lowerings=2 if self.spec is not None else 1,
-        )(jax.jit(_reset, donate_argnums=(0,)))
+        )(jax.jit(engine_reset, donate_argnums=(0,)))
         self._prefill_fn = declare_invariants(
             "engine.prefill", host_syncs=1, donated=("pool",),
             forbid_f32_roundtrip_on=("kv",),
             max_lowerings=n_windows * n_chunks, static_argnums=(5,),
-        )(jax.jit(_prefill, donate_argnums=(1,), static_argnums=(5,)))
+        )(jax.jit(engine_prefill, donate_argnums=(1,),
+                   static_argnums=(5,)))
         self._decode_fn = declare_invariants(
             "engine.decode", host_syncs=1, donated=("pool",),
             forbid_f32_roundtrip_on=("kv",),
             max_lowerings=n_windows, static_argnums=(7,),
-        )(jax.jit(_decode, donate_argnums=(1,), static_argnums=(7,)))
+        )(jax.jit(engine_decode, donate_argnums=(1,),
+                   static_argnums=(7,)))
         self._spec_prefill_fn = declare_invariants(
             "engine.spec_prefill", host_syncs=1, donated=("dpool", "vpool"),
             forbid_f32_roundtrip_on=("kv",),
             max_lowerings=n_windows * n_chunks, static_argnums=(7,),
-        )(jax.jit(_spec_prefill, donate_argnums=(2, 3), static_argnums=(7,)))
+        )(jax.jit(engine_spec_prefill, donate_argnums=(2, 3),
+                   static_argnums=(7,)))
         self._copy_page_fn = declare_invariants(
             "engine.copy_page", host_syncs=1, donated=("pool", "dpool"),
             forbid_f32_roundtrip_on=("kv",),
-        )(jax.jit(_copy_page, donate_argnums=(0, 1)))
-        self._sample_fn = jax.jit(lambda lg, p: smp.sample(
-            lg, scfg, smp.token_key(base_key, p)))
+        )(jax.jit(engine_copy_page, donate_argnums=(0, 1)))
+        def engine_sample(lg, p):
+            with jax.named_scope("sample"):
+                return smp.sample(lg, scfg, smp.token_key(base_key, p))
+
+        self._sample_fn = jax.jit(engine_sample)
 
     def _first_token(self, logits_row, pos: int) -> int:
         """Token emitted from a prefill tail chunk's last-position logits.
@@ -757,16 +824,15 @@ class Engine:
         self._ph = {}
         p0 = self.stats["prefill_tokens"]
         a0 = self.stats["accepted_tokens"]
-        t0 = self.clock()
-        try:
-            out = self._step_inner()
-        except AssertionError:
-            raise
-        except Exception as e:
-            self.last_fault = e
-            out = self._absorb_fault()
-        wall = self.clock() - t0
-        self._ph["total"] = wall
+        with _Phase(self, "total", "step", step_num=self.ticks) as tick:
+            try:
+                out = self._step_inner()
+            except AssertionError:
+                raise
+            except Exception as e:
+                self.last_fault = e
+                out = self._absorb_fault()
+        wall = self._ph["total"]
         self.last_step = {
             "wall_s": wall,
             "phases": self._ph,
@@ -774,17 +840,14 @@ class Engine:
             "decode_tokens": self.stats["accepted_tokens"] - a0,
         }
         if self.tracer is not None:
-            self.tracer.span("step", None, t0, t0 + wall,
+            self.tracer.span("step", None, tick.t0, tick.t1,
                              **{k: round(v, 9)
                                 for k, v in self._ph.items()})
         return out
 
     def _step_inner(self) -> List[RequestResult]:
-        clk = self.clock
-        ph = self._ph
-        t_in = clk()
-        self._admit()
-        ph["admit"] = clk() - t_in
+        with _Phase(self, "admit"):
+            self._admit()
         prefilling = [s.idx for s in self.slots if s.stage == PREFILL]
         decoding = [s.idx for s in self.slots if s.stage == DECODE]
         action = self.scheduler.next_action(prefilling, decoding)
@@ -801,35 +864,35 @@ class Engine:
             # the chunk's last query sits at absolute position hi-1
             self._debug_check_window(window, hi, "prefill")
             table = self._dispatch_table()
-            t_d0 = clk()
-            if self.spec is not None:
-                last_logits, self.draft_pool, self.pool = \
-                    self._spec_prefill_fn(
-                        self.spec.draft_params, self.params, self.draft_pool,
-                        self.pool, table, jnp.int32(slot.idx), chunk, window)
-            else:
-                last_logits, self.pool = self._prefill_fn(
-                    self.params, self.pool, table, jnp.int32(slot.idx),
-                    chunk, window)
-            t_d1 = clk()
-            ph["prefill_dispatch"] = t_d1 - t_d0
+            with _Phase(self, "prefill_dispatch", lo=lo, hi=hi,
+                        window=window) as dispatch:
+                if self.spec is not None:
+                    last_logits, self.draft_pool, self.pool = \
+                        self._spec_prefill_fn(
+                            self.spec.draft_params, self.params,
+                            self.draft_pool, self.pool, table,
+                            jnp.int32(slot.idx), chunk, window)
+                else:
+                    last_logits, self.pool = self._prefill_fn(
+                        self.params, self.pool, table, jnp.int32(slot.idx),
+                        chunk, window)
             slot.prefill_done = hi
             self.stats["prefill_ticks"] += 1
             self.stats["prefill_tokens"] += hi - lo
             emitted_tail = 0
             if hi == slot.prompt.size:
-                if self.paged and self.prefix is not None:
-                    # the prompt's KV is complete: register every page-
-                    # aligned prefix for future admissions. The slot's own
-                    # pages up to the inserted length are now shared —
-                    # future in-place writes there must copy first.
-                    ins = self.prefix.insert(slot.prompt, slot.pages, hi)
-                    slot.n_shared = max(slot.n_shared,
-                                        ins // self.page_size)
-                    self._note_pages()
-                tok = self._first_token(last_logits[0], hi)
-                t_s1 = clk()
-                ph["host_sync"] = t_s1 - t_d1
+                with _Phase(self, "host_sync") as sync:
+                    if self.paged and self.prefix is not None:
+                        # the prompt's KV is complete: register every page-
+                        # aligned prefix for future admissions. The slot's
+                        # own pages up to the inserted length are now
+                        # shared — future in-place writes there must copy
+                        # first.
+                        ins = self.prefix.insert(slot.prompt, slot.pages, hi)
+                        slot.n_shared = max(slot.n_shared,
+                                            ins // self.page_size)
+                        self._note_pages()
+                    tok = self._first_token(last_logits[0], hi)
                 self.stats["host_syncs"] += 1
                 # the speculative healing chunk re-feeds [prev, last]: after
                 # prefill, pos-1 holds the last prompt token
@@ -839,57 +902,58 @@ class Engine:
                 # finishes inside it, and its finish instant must account
                 # for this chunk's token
                 if self.tracer is not None:
-                    self.tracer.span("prefill", uid, t_d0, t_s1,
+                    self.tracer.span("prefill", uid, dispatch.t0, sync.t1,
                                      lo=lo, hi=hi, tokens=emitted_tail)
-                self._emit(slot, tok, finished)
-                ph["token_fanout"] = clk() - t_s1
+                with _Phase(self, "token_fanout"):
+                    self._emit(slot, tok, finished)
             elif self.tracer is not None:
-                self.tracer.span("prefill", uid, t_d0, clk(),
+                self.tracer.span("prefill", uid, dispatch.t0, self.clock(),
                                  lo=lo, hi=hi, tokens=emitted_tail)
         elif action.kind == DECODE and self.spec is not None:
             finished = self._spec_decode(action, finished)
         elif action.kind == DECODE:
             k_steps = self.scheduler.cfg.decode_steps
-            t_d0 = clk()
-            tokens = np.zeros((self.n_slots, 1), np.int32)
-            active = np.zeros((self.n_slots,), bool)
-            eos = np.full((self.n_slots,), -1, np.int32)
-            budget = np.ones((self.n_slots,), np.int32)
-            for i in action.slots:
-                slot = self.slots[i]
-                # capacity growth can exhaust the arena — blame only the
-                # slot being grown, not the whole dispatch batch
-                self._fault_phase = ("slots", [i])
-                tokens[i, 0] = slot.last_token
-                active[i] = True
-                if slot.eos_id is not None:
-                    eos[i] = slot.eos_id
-                budget[i] = slot.max_new_tokens - len(slot.result.tokens)
-                if self.paged:
-                    # deepest write this dispatch: pos + live steps (frozen
-                    # slots rewrite their freeze position, already covered)
-                    self._ensure_capacity(
-                        slot, min(self._slot_pos(slot) + k_steps,
-                                  int(slot.prompt.size)
-                                  + slot.max_new_tokens))
-            # past here a fault hits the batched dispatch itself: every
-            # slot in the action is the blast radius
-            self._fault_phase = ("slots", list(action.slots))
-            # the deepest live slot after k_steps attends positions
-            # <= max(pos) + k_steps - 1  ->  window covers max(pos) + k_steps
-            needed = max(self._slot_pos(self.slots[i])
-                         for i in action.slots) + k_steps
-            window = self._window(needed)
-            self._debug_check_window(window, needed, "decode")
-            toks, emitted, self.pool = self._decode_fn(
-                self.params, self.pool, self._dispatch_table(active),
-                jnp.asarray(tokens), jnp.asarray(active), jnp.asarray(eos),
-                jnp.asarray(budget), window)
-            t_d1 = clk()
-            ph["decode_scan"] = t_d1 - t_d0
-            toks, emitted = np.asarray(toks), np.asarray(emitted)
-            t_s1 = clk()
-            ph["host_sync"] = t_s1 - t_d1
+            with _Phase(self, "decode_scan", live=len(action.slots)) as scan:
+                tokens = np.zeros((self.n_slots, 1), np.int32)
+                active = np.zeros((self.n_slots,), bool)
+                eos = np.full((self.n_slots,), -1, np.int32)
+                budget = np.ones((self.n_slots,), np.int32)
+                for i in action.slots:
+                    slot = self.slots[i]
+                    # capacity growth can exhaust the arena — blame only
+                    # the slot being grown, not the whole dispatch batch
+                    self._fault_phase = ("slots", [i])
+                    tokens[i, 0] = slot.last_token
+                    active[i] = True
+                    if slot.eos_id is not None:
+                        eos[i] = slot.eos_id
+                    budget[i] = (slot.max_new_tokens
+                                 - len(slot.result.tokens))
+                    if self.paged:
+                        # deepest write this dispatch: pos + live steps
+                        # (frozen slots rewrite their freeze position,
+                        # already covered)
+                        self._ensure_capacity(
+                            slot, min(self._slot_pos(slot) + k_steps,
+                                      int(slot.prompt.size)
+                                      + slot.max_new_tokens))
+                # past here a fault hits the batched dispatch itself: every
+                # slot in the action is the blast radius
+                self._fault_phase = ("slots", list(action.slots))
+                # the deepest live slot after k_steps attends positions
+                # <= max(pos) + k_steps - 1  ->  window covers
+                # max(pos) + k_steps
+                needed = max(self._slot_pos(self.slots[i])
+                             for i in action.slots) + k_steps
+                window = self._window(needed)
+                scan.ann.set_metadata(window=window)
+                self._debug_check_window(window, needed, "decode")
+                toks, emitted, self.pool = self._decode_fn(
+                    self.params, self.pool, self._dispatch_table(active),
+                    jnp.asarray(tokens), jnp.asarray(active),
+                    jnp.asarray(eos), jnp.asarray(budget), window)
+            with _Phase(self, "host_sync") as sync:
+                toks, emitted = np.asarray(toks), np.asarray(emitted)
             self.stats["host_syncs"] += 1
             self.stats["device_steps"] += k_steps
             # every slot live at dispatch burns all k_steps device steps —
@@ -906,14 +970,15 @@ class Engine:
                 per_slot = emitted.sum(axis=0)
                 for i in action.slots:
                     self.tracer.span("decode", self.slots[i].result.uid,
-                                     t_d0, t_s1, tokens=int(per_slot[i]),
+                                     scan.t0, sync.t1,
+                                     tokens=int(per_slot[i]),
                                      k_steps=k_steps)
-            for t in range(k_steps):
-                for i in action.slots:
-                    if emitted[t, i]:
-                        self._emit(self.slots[i], int(toks[t, i]), finished)
-            t_f1 = clk()
-            ph["token_fanout"] = t_f1 - t_s1
+            with _Phase(self, "token_fanout"):
+                for t in range(k_steps):
+                    for i in action.slots:
+                        if emitted[t, i]:
+                            self._emit(self.slots[i], int(toks[t, i]),
+                                       finished)
             self.stats["decode_ticks"] += 1
             self.stats["decode_slot_steps"] += int(emitted.sum())
 
@@ -1027,52 +1092,51 @@ class Engine:
         deepest slot's verify writes stay inside the cache (the vmapped KV
         scatter clamps out-of-range starts, which would corrupt valid
         history)."""
-        clk = self.clock
-        ph = self._ph
-        t_d0 = clk()
-        prev = np.zeros((self.n_slots, 1), np.int32)
-        tokens = np.zeros((self.n_slots, 1), np.int32)
-        active = np.zeros((self.n_slots,), bool)
-        eos = np.full((self.n_slots,), -1, np.int32)
-        budget = np.ones((self.n_slots,), np.int32)
-        for i in action.slots:
-            slot = self.slots[i]
-            prev[i, 0] = slot.prev_token
-            tokens[i, 0] = slot.last_token
-            active[i] = True
-            if slot.eos_id is not None:
-                eos[i] = slot.eos_id
-            budget[i] = slot.max_new_tokens - len(slot.result.tokens)
-        max_pos = max(self._slot_pos(self.slots[i]) for i in action.slots)
-        k_eff, c_eff = self.spec.plan(max_pos, self.max_seq,
-                                      int(budget[active].max()))
-        if self.paged:
+        with _Phase(self, "decode_scan", live=len(action.slots)) as scan:
+            prev = np.zeros((self.n_slots, 1), np.int32)
+            tokens = np.zeros((self.n_slots, 1), np.int32)
+            active = np.zeros((self.n_slots,), bool)
+            eos = np.full((self.n_slots,), -1, np.int32)
+            budget = np.ones((self.n_slots,), np.int32)
             for i in action.slots:
                 slot = self.slots[i]
-                self._fault_phase = ("slots", [i])
-                # the healing chunk's first write lands at pos-1 — possibly
-                # inside a shared page (copy-on-write); the verify tail is
-                # the deepest write (plan() keeps it in-bounds)
-                self._ensure_writable(slot, self._slot_pos(slot) - 1)
-                self._ensure_capacity(
-                    slot, self._slot_pos(slot) + c_eff * (k_eff + 1))
-        self._fault_phase = ("slots", list(action.slots))
-        # deepest attend: the last cycle's verify chunk tail
-        needed = max_pos + c_eff * (k_eff + 1)
-        window = self._window(needed)
-        self._debug_check_window(window, needed, "speculative")
-        toks, emitted, n_acc, n_drafted, self.draft_pool, self.pool = \
-            self.spec.spec_fn(
-                self.spec.draft_params, self.params, self.draft_pool,
-                self.pool, self._dispatch_table(active), jnp.asarray(prev),
-                jnp.asarray(tokens), jnp.asarray(active), jnp.asarray(eos),
-                jnp.asarray(budget), k_eff, c_eff, window)
-        t_d1 = clk()
-        ph["decode_scan"] = t_d1 - t_d0
-        toks, emitted = np.asarray(toks), np.asarray(emitted)
-        n_acc, n_drafted = np.asarray(n_acc), np.asarray(n_drafted)
-        t_s1 = clk()
-        ph["host_sync"] = t_s1 - t_d1
+                prev[i, 0] = slot.prev_token
+                tokens[i, 0] = slot.last_token
+                active[i] = True
+                if slot.eos_id is not None:
+                    eos[i] = slot.eos_id
+                budget[i] = slot.max_new_tokens - len(slot.result.tokens)
+            max_pos = max(self._slot_pos(self.slots[i])
+                          for i in action.slots)
+            k_eff, c_eff = self.spec.plan(max_pos, self.max_seq,
+                                          int(budget[active].max()))
+            if self.paged:
+                for i in action.slots:
+                    slot = self.slots[i]
+                    self._fault_phase = ("slots", [i])
+                    # the healing chunk's first write lands at pos-1 —
+                    # possibly inside a shared page (copy-on-write); the
+                    # verify tail is the deepest write (plan() keeps it
+                    # in-bounds)
+                    self._ensure_writable(slot, self._slot_pos(slot) - 1)
+                    self._ensure_capacity(
+                        slot, self._slot_pos(slot) + c_eff * (k_eff + 1))
+            self._fault_phase = ("slots", list(action.slots))
+            # deepest attend: the last cycle's verify chunk tail
+            needed = max_pos + c_eff * (k_eff + 1)
+            window = self._window(needed)
+            scan.ann.set_metadata(window=window)
+            self._debug_check_window(window, needed, "speculative")
+            toks, emitted, n_acc, n_drafted, self.draft_pool, self.pool = \
+                self.spec.spec_fn(
+                    self.spec.draft_params, self.params, self.draft_pool,
+                    self.pool, self._dispatch_table(active),
+                    jnp.asarray(prev), jnp.asarray(tokens),
+                    jnp.asarray(active), jnp.asarray(eos),
+                    jnp.asarray(budget), k_eff, c_eff, window)
+        with _Phase(self, "host_sync") as sync:
+            toks, emitted = np.asarray(toks), np.asarray(emitted)
+            n_acc, n_drafted = np.asarray(n_acc), np.asarray(n_drafted)
         self.stats["host_syncs"] += 1
         # k_eff drafter invocations (healing chunk included) + 1 verify
         # per cycle
@@ -1086,18 +1150,17 @@ class Engine:
             per_slot = emitted.sum(axis=0)
             for i in action.slots:
                 self.tracer.span("spec", self.slots[i].result.uid,
-                                 t_d0, t_s1, tokens=int(per_slot[i]),
+                                 scan.t0, sync.t1, tokens=int(per_slot[i]),
                                  drafted=int(n_drafted[i]),
                                  accepted=int(n_acc[i]),
                                  k=k_eff, cycles=c_eff)
         # nonzero is row-major (t ascending), so per-slot emission order is
         # preserved without scanning all c*(k+1) x n_slots cells in Python
-        for t, i in zip(*np.nonzero(emitted)):
-            slot = self.slots[i]
-            slot.prev_token = slot.last_token
-            self._emit(slot, int(toks[t, i]), finished)
-        t_f1 = clk()
-        ph["token_fanout"] = t_f1 - t_s1
+        with _Phase(self, "token_fanout"):
+            for t, i in zip(*np.nonzero(emitted)):
+                slot = self.slots[i]
+                slot.prev_token = slot.last_token
+                self._emit(slot, int(toks[t, i]), finished)
         self.stats["decode_ticks"] += 1
         self.stats["decode_slot_steps"] += int(emitted.sum())
         return finished

@@ -89,7 +89,7 @@ import numpy as np
 
 from repro import telemetry
 from repro.serving.admission import AdmissionController
-from repro.serving.engine import FREE, Engine, Request
+from repro.serving.engine import FREE, Annotation, Engine, Request
 
 Event = Tuple[Any, ...]   # ("token", index, token) | ("done", info_dict)
 
@@ -100,9 +100,6 @@ class ServiceConfig:
                                     # in-flight bound = n_slots + queue_depth
     default_deadline_s: Optional[float] = None   # per-request override wins
     retry_after_s: float = 0.25     # advertised on 429 responses
-    telemetry: bool = True          # metrics registry + phase/latency
-                                    # histograms (GET /metrics); off for the
-                                    # bench overhead-control phase
 
 
 class Ticket:
@@ -181,37 +178,27 @@ class Service:
         # injectable clock once the engine is attached (tests inject a
         # fake clock here and everything downstream stays deterministic)
         engine.clock = self.clock
-        self.registry: Optional[telemetry.MetricsRegistry] = None
-        self._phase_hists: Dict[str, telemetry.Histogram] = {}
-        self._ttft_hist: Optional[telemetry.Histogram] = None
-        self._latency_hist: Optional[telemetry.Histogram] = None
-        if self.cfg.telemetry:
-            sch = telemetry.schema
-            reg = self.registry = telemetry.MetricsRegistry()
-            reg.register_stats(sch.SERVICE_PREFIX, self.stats,
-                               sch.SERVICE_STATS)
-            reg.register_stats(sch.ENGINE_PREFIX, engine.stats,
-                               sch.ENGINE_STATS)
-            for phase in sch.PHASES:
-                self._phase_hists[phase] = reg.histogram(
-                    sch.PHASE_HISTOGRAM,
-                    "per-engine-step wall time by phase (seconds)",
-                    buckets=sch.PHASE_BUCKETS_S, phase=phase)
-            self._ttft_hist = reg.histogram(
-                sch.TTFT_HISTOGRAM,
-                "submit-to-first-token latency (seconds)",
-                buckets=sch.LATENCY_BUCKETS_S)
-            self._latency_hist = reg.histogram(
-                sch.LATENCY_HISTOGRAM,
-                "submit-to-finish latency (seconds)",
-                buckets=sch.LATENCY_BUCKETS_S)
+        sch = telemetry.schema
+        reg = self.registry = telemetry.MetricsRegistry()
+        reg.register_stats(sch.SERVICE_PREFIX, self.stats, sch.SERVICE_STATS)
+        reg.register_stats(sch.ENGINE_PREFIX, engine.stats, sch.ENGINE_STATS)
+        self._phase_hists: Dict[str, telemetry.Histogram] = {
+            phase: reg.histogram(
+                sch.PHASE_HISTOGRAM,
+                "per-engine-step wall time by phase (seconds)",
+                buckets=sch.PHASE_BUCKETS_S, phase=phase)
+            for phase in sch.PHASES}
+        self._ttft_hist = reg.histogram(
+            sch.TTFT_HISTOGRAM, "submit-to-first-token latency (seconds)",
+            buckets=sch.LATENCY_BUCKETS_S)
+        self._latency_hist = reg.histogram(
+            sch.LATENCY_HISTOGRAM, "submit-to-finish latency (seconds)",
+            buckets=sch.LATENCY_BUCKETS_S)
 
     def render_metrics(self) -> str:
         """Prometheus text exposition of every stat + histogram. Called
         on whatever thread owns the service (the pump, for the HTTP
         front door) — rendering reads the live dicts directly."""
-        if self.registry is None:
-            return "# telemetry disabled (ServiceConfig.telemetry=False)\n"
         return self.registry.render()
 
     # ------------------------------------------------------------- admission
@@ -327,10 +314,9 @@ class Service:
         ticket.t_finish = self.clock()
         self.tickets.pop(ticket.uid, None)
         self.stats[counter] += 1
-        if self._latency_hist is not None:
-            self._latency_hist.observe(ticket.latency_s)
-            if ticket.ttft_s is not None:
-                self._ttft_hist.observe(ticket.ttft_s)
+        self._latency_hist.observe(ticket.latency_s)
+        if ticket.ttft_s is not None:
+            self._ttft_hist.observe(ticket.ttft_s)
         if ticket.sink is not None:
             lat = ticket.latency_s
             ttft = ticket.ttft_s
@@ -354,13 +340,15 @@ class Service:
     def expire_deadlines(self) -> int:
         """Evict every live request whose deadline has passed — queued OR
         mid-flight (mid-prefill eviction frees the slot's pages at once).
-        Runs at the top of every ``step``; returns how many expired."""
-        now = self.clock()
-        expired = [t for t in self.tickets.values()
-                   if t.deadline is not None and now > t.deadline]
-        for t in expired:
-            self.engine.cancel(t.uid)
-            self._finish(t, "deadline", "expired")
+        Runs at the top of every ``step``, as the profiler annotation
+        ``repro.service.expire``; returns how many expired."""
+        with Annotation("repro.service.expire", self.clock) as ann:
+            now = ann.t0
+            expired = [t for t in self.tickets.values()
+                       if t.deadline is not None and now > t.deadline]
+            for t in expired:
+                self.engine.cancel(t.uid)
+                self._finish(t, "deadline", "expired")
         return len(expired)
 
     @property
@@ -387,34 +375,36 @@ class Service:
         thread. The engine's own per-step measurement
         (``Engine.last_step``) feeds BOTH the admission controller's
         EWMAs and the phase histograms — one clock read per step, two
-        consumers, no service-side re-timing."""
-        self.expire_deadlines()
-        if not self.engine.has_work:
-            return 0
-        n = 0
-        try:
-            results = self.engine.step()
-        except Exception:
-            self.stats["faults"] += 1
-            self._fail_all()
-            return 0
-        last = self.engine.last_step
-        if self.admission is not None:
-            self.admission.observe_step(last)
-        if self._phase_hists and last:
-            for phase, dt in last["phases"].items():
-                h = self._phase_hists.get(phase)
-                if h is not None:
-                    h.observe(dt)
-        for res in results:
-            ticket = self.tickets.get(res.uid)
-            if ticket is not None:
-                if res.finish_reason == "error":
-                    self._finish(ticket, "error", "faults")
-                else:
-                    self._finish(ticket, res.finish_reason, "completed")
-                n += 1
-        return n
+        consumers, no service-side re-timing. The tick is the profiler
+        annotation ``repro.service.step``."""
+        with Annotation("repro.service.step", self.clock):
+            self.expire_deadlines()
+            if not self.engine.has_work:
+                return 0
+            n = 0
+            try:
+                results = self.engine.step()
+            except Exception:
+                self.stats["faults"] += 1
+                self._fail_all()
+                return 0
+            last = self.engine.last_step
+            if self.admission is not None:
+                self.admission.observe_step(last)
+            if last:
+                for phase, dt in last["phases"].items():
+                    h = self._phase_hists.get(phase)
+                    if h is not None:
+                        h.observe(dt)
+            for res in results:
+                ticket = self.tickets.get(res.uid)
+                if ticket is not None:
+                    if res.finish_reason == "error":
+                        self._finish(ticket, "error", "faults")
+                    else:
+                        self._finish(ticket, res.finish_reason, "completed")
+                    n += 1
+            return n
 
     def begin_drain(self) -> None:
         """Stop admitting; in-flight and queued requests keep running."""
@@ -560,21 +550,32 @@ class HttpFrontDoor:
         the inbox and stepping on one thread keeps submit/cancel strictly
         between ticks — the same interleaving the sync tests drive by
         hand. Each iteration flushes everything it staged (token events +
-        operation replies) to the event loop in one batch."""
+        operation replies) to the event loop in one batch, stamped with
+        the service-clock time it was staged at.
+
+        Profiler annotations, each with ``t_engine`` (the service clock
+        as it opened): ``repro.door.inbox`` around the inbox service and
+        ``repro.door.idle`` around the idle park, so that device idle
+        time with no work to do is told apart from host-bound idle
+        time."""
+        clock = self.service.clock
         while not self._stop_pump.is_set():
             # wall time on purpose — see _beat in __init__
             self._beat = telemetry.wall_clock()
             with self.lock:
-                self._serve_inbox()
+                with Annotation("repro.door.inbox", clock):
+                    self._serve_inbox()
                 busy = self.service.has_work
                 if busy:
                     self.service.step()
                 staged, self._staged = self._staged, {}
                 replies, self._replies = self._replies, []
             if staged or replies:
-                self._loop.call_soon_threadsafe(self._flush, staged, replies)
+                self._loop.call_soon_threadsafe(self._flush, staged,
+                                                replies, clock())
             if not busy:
-                self._kick.wait(self.pump_idle_s)
+                with Annotation("repro.door.idle", clock):
+                    self._kick.wait(self.pump_idle_s)
                 self._kick.clear()
 
     def _exit_wedged(self, msg: str) -> None:
@@ -629,17 +630,24 @@ class HttpFrontDoor:
             else:                                    # ("idle", fut)
                 self._replies.append((op[1], not svc.has_work))
 
-    @staticmethod
-    def _flush(staged: Dict[asyncio.Queue, List[Event]],
-               replies: List[Tuple[asyncio.Future, Any]]) -> None:
-        for queue, evs in staged.items():
-            queue.put_nowait(evs)              # one item per stream per step
-        for fut, value in replies:
-            if not fut.done():
-                if isinstance(value, Exception):
-                    fut.set_exception(value)
-                else:
-                    fut.set_result(value)
+    def _flush(self, staged: Dict[asyncio.Queue, List[Event]],
+               replies: List[Tuple[asyncio.Future, Any]],
+               t_staged: float) -> None:
+        """Deliver one pump iteration's batch (event-loop thread), as the
+        profiler annotation ``repro.door.flush``: ``t_staged`` is when the
+        pump staged the batch and ``t_engine`` when the loop got to it,
+        both on the service clock, so their difference is how long the
+        batch's tokens waited for the loop."""
+        with Annotation("repro.door.flush", self.service.clock,
+                        t_staged=t_staged):
+            for queue, evs in staged.items():
+                queue.put_nowait(evs)          # one item per stream per step
+            for fut, value in replies:
+                if not fut.done():
+                    if isinstance(value, Exception):
+                        fut.set_exception(value)
+                    else:
+                        fut.set_result(value)
 
     async def _ask(self, op: Tuple[Any, ...]) -> Any:
         """Post an operation needing a reply; the last element must be a

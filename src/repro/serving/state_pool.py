@@ -11,7 +11,9 @@ Built on ``init_decode_state(..., params=...)`` so HQP-compacted artifacts —
 whose pruned KV heads / Mamba channels physically shrank — size their own
 pool; the engine never consults the config for cache widths.
 
-Slot ops are pure functions (jitted by the engine):
+Slot ops are pure functions (jitted by the engine), each under a
+``jax.named_scope`` that names its device operations in a profile
+(``kv_gather``, ``kv_scatter``, ``kv_reset``, ``kv_select``):
 
   gather_slot(pool, slot)          -> single-slot state (batch=1, scalar pos)
   scatter_slot(pool, slot, state)  -> pool with that slot replaced
@@ -101,9 +103,10 @@ def gather_slot(pool: Dict[str, Any], slot: jax.Array,
     mode KV entries are the shared arena and pass through whole — the
     caller attaches the slot's page-table row as ``state["pages"]``."""
     sl = lambda leaf: jax.lax.dynamic_slice_in_dim(leaf, slot, 1, SLOT_AXIS)
-    caches = (_map_entries(pool["caches"], lambda leaf: leaf, sl)
-              if paged else jax.tree.map(sl, pool["caches"]))
-    pos = jax.lax.dynamic_slice(pool["pos"], (slot,), (1,))[0]
+    with jax.named_scope("kv_gather"):
+        caches = (_map_entries(pool["caches"], lambda leaf: leaf, sl)
+                  if paged else jax.tree.map(sl, pool["caches"]))
+        pos = jax.lax.dynamic_slice(pool["pos"], (slot,), (1,))[0]
     return {"caches": caches, "pos": pos}
 
 
@@ -115,13 +118,14 @@ def scatter_slot(pool: Dict[str, Any], slot: jax.Array,
     landed through the page table) and replace the pool's wholesale."""
     upd = lambda leaf, u: jax.lax.dynamic_update_slice_in_dim(
         leaf, u.astype(leaf.dtype), slot, SLOT_AXIS)
-    caches = (_map_entries(pool["caches"], lambda leaf, u: u, upd,
-                           state["caches"])
-              if paged else jax.tree.map(upd, pool["caches"],
-                                         state["caches"]))
-    pos = jax.lax.dynamic_update_slice(
-        pool["pos"], jnp.reshape(state["pos"], (1,)).astype(jnp.int32),
-        (slot,))
+    with jax.named_scope("kv_scatter"):
+        caches = (_map_entries(pool["caches"], lambda leaf, u: u, upd,
+                               state["caches"])
+                  if paged else jax.tree.map(upd, pool["caches"],
+                                             state["caches"]))
+        pos = jax.lax.dynamic_update_slice(
+            pool["pos"], jnp.reshape(state["pos"], (1,)).astype(jnp.int32),
+            (slot,))
     return {"caches": caches, "pos": pos}
 
 
@@ -142,11 +146,12 @@ def reset_slot(pool: Dict[str, Any], slot: jax.Array,
     pages)."""
     upd = lambda leaf, u: jax.lax.dynamic_update_slice_in_dim(
         leaf, u.astype(leaf.dtype), slot, SLOT_AXIS)
-    caches = _map_entries(pool["caches"], lambda leaf, u: leaf, upd,
-                          template["caches"])
-    pos = jax.lax.dynamic_update_slice(
-        pool["pos"],
-        jnp.reshape(jnp.asarray(pos0, jnp.int32), (1,)), (slot,))
+    with jax.named_scope("kv_reset"):
+        caches = _map_entries(pool["caches"], lambda leaf, u: leaf, upd,
+                              template["caches"])
+        pos = jax.lax.dynamic_update_slice(
+            pool["pos"],
+            jnp.reshape(jnp.asarray(pos0, jnp.int32), (1,)), (slot,))
     return {"caches": caches, "pos": pos}
 
 
@@ -187,10 +192,12 @@ def select_slots(new: Dict[str, Any], old: Dict[str, Any],
         mask = active.reshape((1, -1) + (1,) * (n.ndim - 2))
         return jnp.where(mask, n, o)
 
-    caches = (_map_entries(new["caches"], lambda n, o: n, sel,
-                           old["caches"])
-              if paged else jax.tree.map(sel, new["caches"], old["caches"]))
-    pos = jnp.where(active, new["pos"], old["pos"])
+    with jax.named_scope("kv_select"):
+        caches = (_map_entries(new["caches"], lambda n, o: n, sel,
+                               old["caches"])
+                  if paged else jax.tree.map(sel, new["caches"],
+                                             old["caches"]))
+        pos = jnp.where(active, new["pos"], old["pos"])
     return {"caches": caches, "pos": pos}
 
 

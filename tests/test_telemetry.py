@@ -13,8 +13,7 @@ The load-bearing guarantees (DESIGN.md §16):
     and ``queued + active`` tiles the ``request`` envelope — including
     under injected engine faults and admit/cancel/expiry storms;
   * ``GET /metrics`` on the front door serves the full declared metric
-    set mid-conversation, and ``ServiceConfig(telemetry=False)`` turns
-    the whole plane off;
+    set mid-conversation;
   * ``Engine.last_step`` is the single measurement source: the service
     feeds the phase histograms and the admission EWMAs from it, through
     one injected clock shared by engine, service, and recorder.
@@ -288,17 +287,6 @@ def test_live_stats_keys_are_all_declared(setup):
     # every declared family renders before any traffic
     parsed = parse_exposition(svc.render_metrics())
     assert set(schema.metric_names()) <= set(parsed["types"])
-
-
-def test_telemetry_off_is_off(setup):
-    cfg, eng = setup
-    svc = Service(eng, ServiceConfig(queue_depth=2, telemetry=False))
-    assert svc.registry is None
-    assert svc.render_metrics().startswith("# telemetry disabled")
-    t = svc.submit(Request(prompt=_prompts(cfg, [6])[0], max_new_tokens=2))
-    while svc.has_work:
-        svc.step()
-    assert t.finish_reason == "length"            # serving path unaffected
 
 
 def test_last_step_feeds_phase_hists_and_latency(setup):

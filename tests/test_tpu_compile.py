@@ -120,6 +120,36 @@ def test_quantize_rowwise_compiles(one_chip, m):
                                    sharding=one_chip),))
 
 
+def test_kernel_operations_keep_their_names(one_chip):
+    """Inside a step, each Pallas kernel's operation is named after the
+    kernel (its ``pallas_call`` ``name=``), which is how a profile of the
+    chip tells the kernels apart."""
+    import re
+    from repro.kernels.int8_matmul import int8_matmul_pallas
+    from repro.kernels.prefill_attention import paged_prefill_attention_pallas
+    from repro.kernels.quantize import quantize_rowwise_pallas
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                  sharding=one_chip)
+
+    def step(x, w, ws, q, k, v, ks, vs, start, pages):
+        xq, xs = quantize_rowwise_pallas(x)
+        y = int8_matmul_pallas(xq, w, xs, ws)
+        return y, paged_prefill_attention_pallas(q, k, v, ks, vs, start,
+                                                 pages)
+
+    d, f = CFG.d_model, CFG.d_ff
+    args = (spec((B, d), jnp.bfloat16), spec((d, f), jnp.int8),
+            spec((f,), jnp.float32), spec((B, 1, HQ, HD), jnp.bfloat16),
+            *_kv(spec, N_PAGES, PS, "int8"), spec((B,), jnp.int32),
+            spec((B, W // PS), jnp.int32))
+    text = jax.jit(step).lower(*args).compile().as_text()
+    calls = {m.group(1).split(".")[0] for m in re.finditer(
+        r"%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text)}
+    assert calls == {"paged_prefill_attention_pallas", "int8_matmul_pallas",
+                     "quantize_rowwise_pallas"}, calls
+
+
 @pytest.mark.parametrize("hqp", [False, True], ids=["bf16", "hqp_int8"])
 @pytest.mark.parametrize("step", ["engine.prefill", "engine.decode"])
 def test_engine_step_compiles(one_chip, step, hqp):

@@ -87,7 +87,7 @@ def kernel_parity():
     import numpy as np
     from repro import configs
     from repro.kernels.backend import get_backend
-    from repro.kernels.kv_layout import to_store
+    from repro.kernels.kv_layout import page_scales, to_store
     from repro.models.attention import _quant_kv
 
     cfg = configs.get_config("qwen3-0.6b")
@@ -139,9 +139,12 @@ def kernel_parity():
                 pallas.prefill_attention(qs, k, v, k_s, v_s, start_pre),
                 xla.prefill_attention(qs, k, v, k_s, v_s, start_pre),
                 3e-2, 1.5e-1 if int8 else 3e-2)
-        # the paged arena: uint16 words for bf16 (as the engine stores it)
+        # the paged arena as the engine stores it: uint16 words for bf16,
+        # INT8 scales as one (Hkv*ps) row per page
         ak, av, aks, avs = kv(1 + b * n_blk, ps, int8)
-        if not int8:
+        if int8:
+            aks, avs = page_scales(aks), page_scales(avs)
+        else:
             ak, av = to_store(ak, jnp.uint16), to_store(av, jnp.uint16)
         tag = "int8" if int8 else "uint16"
         compare(f"paged_decode/{tag}",

@@ -12,9 +12,9 @@ of trusting the source graph (HALP's argument, applied to our own stack):
                   (~4.8µs/page before PR 6/8 fixed it by storing raw
                   uint16 words). Matching is by result element count of
                   the protected cache leaves, not exact dims — the write
-                  paths reshape the arena (``scatter_pages`` flattens
-                  (pages, page_size) to one axis) but never change its
-                  size, while every non-pathological f32 tensor in these
+                  paths reshape the arena (``merge_layers`` merges the
+                  layer stack's leading dims) but never change its size,
+                  while every non-pathological f32 tensor in these
                   programs is activation-sized, orders of magnitude
                   smaller than an arena.
   donation        every leaf of a declared-donated argument appears in

@@ -45,7 +45,7 @@ class Backend:
     decode_attention_paged / prefill_attention_paged:
                       the same two primitives against a PAGED KV arena —
                       k/v (n_pages, page_size, Hkv, hd) (scales
-                      (n_pages, page_size, Hkv)) plus a (B, n_blk) int32
+                      (n_pages, Hkv*page_size)) plus a (B, n_blk) int32
                       ``pages`` window prefix of each row's page table. On
                       xla: gather-to-contiguous + the contiguous einsum
                       (bit-identity with the contiguous layout by

@@ -42,7 +42,7 @@ def paged_decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                                   pages: jax.Array = None, *,
                                   interpret: bool = False) -> jax.Array:
     """Page-table-indirect decode: q (B, Hq, hd) vs a PAGED arena k/v
-    (n_pages, page_size, Hkv, hd) (scales (n_pages, page_size, Hkv)),
+    (n_pages, page_size, Hkv, hd) (scales (n_pages, Hkv*page_size)),
     start (B,) int32, pages (B, n_blk) int32 — see
     ``paged_prefill_attention_pallas``. Returns (B, Hq, hd) bf16."""
     return paged_prefill_attention_pallas(q[:, None], k, v, k_s, v_s, start,

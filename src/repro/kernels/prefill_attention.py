@@ -12,7 +12,9 @@ score tensor is ever materialized.
 Block layout (DESIGN.md §Backend-registry): every block keeps the arrays'
 last two dims whole, as the TPU tiling rule requires of dims that are not
 (8, 128) multiples — a q/out tile is (1, bq, Hq, hd), a KV page (1, ps, Hkv,
-hd), a scale page (1, Hkv, ps). The kernel walks the heads itself: each KV
+hd), and for a page's scales the (``SCALE_ROWS``, Hkv*ps) tile of rows
+that holds its row, whose lanes [h*ps, (h+1)*ps) are head ``h``'s scales
+along a score tile's columns. The kernel walks the heads itself: each KV
 head's (ps, hd) slice is read once and serves its G = Hq/Hkv query heads
 (GQA), each query head's (bq, hd) rows run their own online softmax.
 ``start`` and the page table are scalar-prefetch (SMEM) operands; the table
@@ -49,19 +51,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.kv_layout import NEG_INF, as_pages, from_store, page_scales
+from repro.kernels.kv_layout import NEG_INF, SCALE_ROWS, as_pages, from_store
+
+
+def _page(b, i, j, tbl, start, bq: int, bk: int):
+    """Physical page the KV block (i, j) of row ``b`` reads. Pages past the
+    tile's deepest causal limit are skipped in the body; pinning their
+    block index to the last visible page means the pipeline never fetches
+    them either (an inactive row's start may be negative: the clamp keeps
+    the table read in bounds)."""
+    last = (start[b] + (i + 1) * bq - 1) // bk
+    return tbl[b, jnp.clip(last, 0, j)]
 
 
 def _kernel(tbl_ref, start_ref, q_ref, k_ref, v_ref, *rest, bq: int, bk: int,
             g: int, n_kv: int, scale: float, quantized: bool):
-    del tbl_ref                     # consumed by the KV index maps only
     if quantized:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
-    start = start_ref[pl.program_id(0)]
-    i, j = pl.program_id(1), pl.program_id(2)
+    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    start = start_ref[b]
     hq, hkv = q_ref.shape[2], k_ref.shape[2]
+    if quantized:               # the page's row in its tile of scale rows
+        row = pl.ds(_page(b, i, j, tbl_ref, start_ref, bq, bk) % SCALE_ROWS,
+                    1)
 
     @pl.when(j == 0)
     def _init():
@@ -78,6 +92,8 @@ def _kernel(tbl_ref, start_ref, q_ref, k_ref, v_ref, *rest, bq: int, bk: int,
                                                           (bq, bk), 0)
         kv_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         visible = kv_pos <= q_pos
+        if quantized:            # one load of the row serves every head
+            ks_row, vs_row = ks_ref[row, :], vs_ref[row, :]
         for h in range(hkv):
             k = from_store(k_ref[0, :, h, :]).astype(jnp.float32)  # (bk, hd)
             v = from_store(v_ref[0, :, h, :]).astype(jnp.float32)
@@ -87,7 +103,8 @@ def _kernel(tbl_ref, start_ref, q_ref, k_ref, v_ref, *rest, bq: int, bk: int,
                     q, k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
                 if quantized:
-                    s = s * ks_ref[0, h:h + 1, :]   # dequant on scores
+                    # dequant on scores
+                    s = s * ks_row[:, h * bk:(h + 1) * bk]
                 s = jnp.where(visible, s, NEG_INF)
                 m_prev, l_prev = m_ref[r], l_ref[r]                 # (bq, 1)
                 m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -95,7 +112,8 @@ def _kernel(tbl_ref, start_ref, q_ref, k_ref, v_ref, *rest, bq: int, bk: int,
                 corr = jnp.exp(m_prev - m_new)
                 l_ref[r] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
                 if quantized:
-                    p = p * vs_ref[0, h:h + 1, :]   # dequant on probabilities
+                    # dequant on probabilities
+                    p = p * vs_row[:, h * bk:(h + 1) * bk]
                 acc_ref[r] = acc_ref[r] * corr + jax.lax.dot_general(
                     p, v, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
@@ -117,7 +135,8 @@ def paged_prefill_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                                    interpret: bool = False) -> jax.Array:
     """q (B, Sq, Hq, hd) at absolute positions start..start+Sq-1 vs a PAGED
     arena: k/v (n_pages, page_size, Hkv, hd) float, uint16 (raw bf16 words)
-    or int8 (then k_s/v_s (n_pages, page_size, Hkv) f32 scales); start (B,)
+    or int8 (then k_s/v_s (n_pages, Hkv*page_size) f32 scales, the arena's
+    own layout: nothing is rearranged per call); start (B,)
     int32; pages (B, n_blk) int32 — the window prefix of each row's page
     table. The KV block size is pinned to ``page_size``; grid step (b, i, j)
     DMAs physical page ``pages[b, j]``. Unallocated table entries point at
@@ -134,13 +153,7 @@ def paged_prefill_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     n_blk = pages.shape[1]
     quantized = k_s is not None
 
-    def kv_page(bb, i, j, tbl, st):
-        # pages past the tile's deepest causal limit are skipped in the
-        # body; pinning their block index to the last visible page means
-        # the pipeline never fetches them either (an inactive row's start
-        # may be negative: the clamp keeps the table read in bounds)
-        last = (st[bb] + (i + 1) * bq - 1) // ps
-        return tbl[bb, jnp.clip(last, 0, j)]
+    kv_page = functools.partial(_page, bq=bq, bk=ps)
 
     inputs = [q, k, v]
     in_specs = [
@@ -151,9 +164,14 @@ def paged_prefill_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                      lambda *a: (kv_page(*a), 0, 0, 0)),
     ]
     if quantized:
-        inputs += [page_scales(k_s), page_scales(v_s)]
-        in_specs += [pl.BlockSpec((1, hkv, ps),
-                                  lambda *a: (kv_page(*a), 0, 0))] * 2
+        # an arena of any size reads whole tiles (the model's arenas
+        # already hold a SCALE_ROWS multiple of pages: no pad there)
+        pad = (-k_s.shape[0]) % SCALE_ROWS
+        inputs += [jnp.pad(t, ((0, pad), (0, 0))) if pad else t
+                   for t in (k_s, v_s)]
+        in_specs += [pl.BlockSpec(
+            (SCALE_ROWS, hkv * ps),
+            lambda *a: (kv_page(*a) // SCALE_ROWS, 0))] * 2
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

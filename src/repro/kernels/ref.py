@@ -117,14 +117,15 @@ def _gathered_window(k, v, k_s, v_s, pages):
     """Materialize a paged arena window as contiguous (B, W, ...) views —
     the xla paged read path IS gather + the contiguous einsum, which is what
     pins paged numerics bit-identical to the contiguous layout."""
-    from repro.kernels.kv_layout import gather_pages
-    g = lambda t: None if t is None else gather_pages(t, pages)
-    return g(k), g(v), g(k_s), g(v_s)
+    from repro.kernels.kv_layout import gather_page_scales, gather_pages
+    s = lambda t: (None if t is None
+                   else gather_page_scales(t, pages, k.shape[1]))
+    return gather_pages(k, pages), gather_pages(v, pages), s(k_s), s(v_s)
 
 
 def paged_prefill_attention_ref(q, k, v, k_s, v_s, start, pages):
     """q: (B, Sq, Hq, hd); k/v: (n_pages, page_size, Hkv, hd) arenas (int8
-    with (n_pages, page_size, Hkv) scales when quantized); pages: (B, n_blk)
+    with (n_pages, Hkv*page_size) scales when quantized); pages: (B, n_blk)
     int32 window prefix of each row's page table; start as the contiguous
     primitive."""
     return cached_attention_ref(q, *_gathered_window(k, v, k_s, v_s, pages),

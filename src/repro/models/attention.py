@@ -121,10 +121,18 @@ class CacheSpec:
     quantized: bool = False     # INT8 KV cache (beyond-paper: HQP applied to KV)
 
 
+def is_kv_entry(entry) -> bool:
+    """True for a position-indexed KV cache entry (pageable); False for
+    recurrent Mamba/xLSTM state (slot-resident, O(1) per slot)."""
+    return isinstance(entry, dict) and ("k" in entry or "k_q" in entry)
+
+
 def init_kv_cache(batch: int, max_seq: int, n_kv_heads: int, hd: int,
                   quantized: bool = False, paged: bool = False) -> dict:
     """``paged=True`` marks the leaves as a shared page arena (``batch`` is
-    ``total_pages``, ``max_seq`` is ``page_size``). bf16 caches — paged
+    ``total_pages``, ``max_seq`` is ``page_size``); its INT8 scales are
+    (total_pages, Hkv*page_size), the layout the cache-attention kernel
+    reads (``kernels.kv_layout``). bf16 caches — paged
     arena and contiguous pool alike — are stored as raw uint16 words: XLA
     CPU's float-normalization pass rewrites bf16 scatter/dynamic_update_
     slice through f32 converts, copying the whole buffer on every write —
@@ -133,13 +141,14 @@ def init_kv_cache(batch: int, max_seq: int, n_kv_heads: int, hd: int,
     the read/write boundaries). int8 quantized leaves scatter in place
     natively and keep their dtype in both layouts."""
     if quantized:
+        s_shape = ((batch, n_kv_heads * max_seq) if paged
+                   else (batch, max_seq, n_kv_heads))
         return {
             "k_q": jnp.zeros((batch, max_seq, n_kv_heads, hd), jnp.int8),
             "v_q": jnp.zeros((batch, max_seq, n_kv_heads, hd), jnp.int8),
-            "k_s": jnp.zeros((batch, max_seq, n_kv_heads), jnp.float32),
-            "v_s": jnp.zeros((batch, max_seq, n_kv_heads), jnp.float32),
+            "k_s": jnp.zeros(s_shape, jnp.float32),
+            "v_s": jnp.zeros(s_shape, jnp.float32),
         }
-    del paged   # dtype no longer depends on the layout
     dt = (jnp.uint16 if L.COMPUTE_DTYPE == jnp.bfloat16
           else L.COMPUTE_DTYPE)
     return {
@@ -167,10 +176,13 @@ def update_kv_cache(cache: dict, k_new: jax.Array, v_new: jax.Array,
     path is a per-row scatter (vmapped dynamic_update_slice).
 
     ``pages`` (B, max_pages) int32 marks the cache as a PAGED arena —
-    leaves are (n_pages, page_size, ...) with no batch axis — and the write
-    becomes a flat per-element scatter through the page table
-    (``kv_layout.scatter_pages``): logical position p of row b lands at
-    ``arena[pages[b, p // page_size], p % page_size]``. The per-token
+    leaves are (n_pages, page_size, ...) with no batch axis, scales (n_pages,
+    Hkv*page_size) — and the write becomes a per-token scatter through the
+    page table (``kv_layout.scatter_pages``): logical position
+    p of row b lands at ``arena[pages[b, p // page_size], p % page_size]``.
+    The model passes the layer stack merged into one arena
+    (``kv_layout.merge_layers``) with the table offset to the layer's
+    pages, so the scatter writes the stack in place. The per-token
     values (INT8 quant included — it is per-(pos, head)) are identical to
     the contiguous write, which is what keeps prefix-cache page reuse
     bit-exact across requests."""
@@ -186,8 +198,14 @@ def update_kv_cache(cache: dict, k_new: jax.Array, v_new: jax.Array,
             new = {"k": k_new.astype(L.COMPUTE_DTYPE),
                    "v": v_new.astype(L.COMPUTE_DTYPE)}
         if pages is not None:
-            from repro.kernels.kv_layout import scatter_pages
-            return {key: scatter_pages(cache[key], new[key], pages, pos)
+            from repro.kernels.kv_layout import (scatter_page_scales,
+                                                 scatter_pages)
+            ps = cache["k_q" if "k_q" in cache else "k"].shape[1]
+            return {key: (scatter_page_scales(cache[key], new[key], pages,
+                                              pos, ps)
+                          if key in ("k_s", "v_s")
+                          else scatter_pages(cache[key], new[key], pages,
+                                             pos))
                     for key in cache}
         from repro.kernels.kv_layout import to_store
         new = {key: to_store(val, cache[key].dtype)
@@ -293,8 +311,14 @@ def attention_forward(p: dict, cfg, x: jax.Array, positions: jax.Array,
     # head counts derive from (possibly HQP-compacted) param shapes
     n_heads = L.out_features(p["wq"]) // hd
     n_kv = L.out_features(p["wk"]) // hd
-    q = _split_heads(L.dense(x, p["wq"]), n_heads, hd)
-    k = _split_heads(L.dense(x, p["wk"]), n_kv, hd)
+    # with qk-norm the projections reach the norm as f32: XLA on a TPU
+    # skips the bf16 rounding of a projection where it fuses it with the
+    # norm, so a bf16 result would be rounded or not depending on the
+    # program around it, and the paged engine's K would differ from serial
+    # decode's
+    acc = jnp.float32 if cfg.qk_norm else None
+    q = _split_heads(L.dense(x, p["wq"], acc), n_heads, hd)
+    k = _split_heads(L.dense(x, p["wk"], acc), n_kv, hd)
     v = _split_heads(L.dense(x, p["wv"]), n_kv, hd)
     if cfg.qk_norm:
         q, k = L.l2norm(q), L.l2norm(k)

@@ -39,8 +39,11 @@ def linear_init(key, d_in, d_out, dtype=COMPUTE_DTYPE):
 
 
 # ---------------------------------------------------------------- dense
-def dense(x: jax.Array, p) -> jax.Array:
+def dense(x: jax.Array, p, out_dtype=None) -> jax.Array:
     """Matmul dispatch: FP weight dict, or a typed ``QuantizedLinear``.
+
+    ``out_dtype``: the FP path's result type (None: the compute dtype); the
+    INT8 kernel returns its own.
 
     The INT8 path intentionally keeps the weight int8 in HLO (bytes halve in
     the roofline memory term); dequant is folded into the matmul epilogue by
@@ -51,7 +54,8 @@ def dense(x: jax.Array, p) -> jax.Array:
     if isinstance(p, QuantizedLinear):
         return kops.int8_matmul(x, p.w_q, p.scale)
     w = p["w"]
-    return jnp.dot(x.astype(COMPUTE_DTYPE), w.astype(COMPUTE_DTYPE))
+    return jnp.dot(x.astype(COMPUTE_DTYPE), w.astype(COMPUTE_DTYPE),
+                   preferred_element_type=out_dtype)
 
 
 def dense_param_bytes(p) -> int:

@@ -7,8 +7,13 @@ are stacked over the ``n_layers/p`` repetitions and the stack is driven by
 ``lax.scan`` (HLO stays O(p) regardless of depth — compile-time and HLO-size
 are depth-independent, which matters at 72 layers x 512 devices).
 
-Decode state is a per-period-position pytree stacked over groups, scanned
-jointly with the params.
+Decode state is a per-period-position pytree stacked over groups. Recurrent
+state and contiguous KV caches are scanned jointly with the params (each
+group's slice in ``xs``, written back through ``ys``); a PAGED KV arena
+rides in the scan's carry whole, its layers merged into one arena
+(``kv_layout.merge_layers``), and each group reads and writes its own
+layer's pages through the page table offset by its index, so no layer's
+arena is sliced out or written back per step.
 """
 from __future__ import annotations
 
@@ -18,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.kv_layout import arena_pages, merge_layers, split_layers
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models import moe as M
@@ -275,9 +281,11 @@ def init_decode_state(cfg, batch: int, max_seq: int,
     suffices.
 
     ``kv_pages=(total_pages, page_size)`` switches the KV caches to the
-    PAGED arena layout: attention leaves become (total_pages, page_size,
-    Hkv, hd) with NO batch/slot axis (the arena is shared through per-slot
-    page tables the caller owns — ``serving.state_pool``), while recurrent
+    PAGED arena layout: attention leaves become (n_pages, page_size, Hkv,
+    hd), INT8 scales (n_pages, Hkv*page_size), with NO batch/slot axis (the
+    arena is shared through per-slot page tables the caller owns —
+    ``serving.state_pool``; ``n_pages`` is ``kv_layout.arena_pages`` of
+    ``total_pages``, whose spare pages are never used), while recurrent
     Mamba/xLSTM leaves keep their per-slot batch axis (recurrent state is
     O(1) per slot; only position-indexed KV pages)."""
     ctx = ctx or default_ctx()
@@ -298,8 +306,8 @@ def init_decode_state(cfg, batch: int, max_seq: int,
         if kind == "attn":
             n_kv = (L.out_features(blk(j)["attn"]["wk"]) // hd
                     if params is not None else cfg.n_kv_heads)
-            kv_b, kv_s = kv_pages if kv_pages is not None else (batch,
-                                                                max_seq)
+            kv_b, kv_s = ((arena_pages(kv_pages[0]), kv_pages[1])
+                          if kv_pages is not None else (batch, max_seq))
             caches.append(stack(lambda: A.init_kv_cache(
                 kv_b, kv_s, n_kv, hd, ctx.quantized_kv,
                 paged=kv_pages is not None)))
@@ -368,22 +376,38 @@ def decode_step(params: dict, cfg, state: dict, tokens: jax.Array,
     positions = (cur + jnp.arange(s) if jnp.ndim(cur) == 0
                  else cur[:, None] + jnp.arange(s)[None, :])
     period = pattern_period(cfg)
+    groups = cfg.n_layers // period
     spec = layer_specs(cfg)[:period]
+    # paged KV arenas ride in the carry, each stack merged into one arena
+    # that every group reads and writes in place through its offset table;
+    # recurrent state and contiguous caches are sliced per group
+    caches = state["caches"]
+    carried = tuple(pages is not None and A.is_kv_entry(e) for e in caches)
+    arenas, n_pages = zip(*(merge_layers(e) if c else (None, 0)
+                            for e, c in zip(caches, carried)))
+    scanned = tuple(None if c else e for e, c in zip(caches, carried))
 
-    def group(x, xs):
-        block_params, caches = xs
+    def group(carry, xs):
+        x, arenas = carry
+        block_params, caches, g = xs
         x = _shard_x(x, ctx)
-        new_caches = []
+        arenas, new_caches = list(arenas), []
         for j, (kind, is_moe) in enumerate(spec):
+            cache, pg = ((arenas[j], pages + g * n_pages[j]) if carried[j]
+                         else (caches[j], pages))
             x, nc, _ = _block_forward(kind, is_moe, block_params[j], cfg, x,
-                                      positions, ctx, caches[j], cur,
-                                      window=window, route=route,
-                                      pages=pages)
+                                      positions, ctx, cache, cur,
+                                      window=window, route=route, pages=pg)
+            if carried[j]:
+                arenas[j], nc = nc, None
             new_caches.append(nc)
-        return x, tuple(new_caches)
+        return (x, tuple(arenas)), tuple(new_caches)
 
-    x, new_caches = jax.lax.scan(group, x,
-                                 (params["blocks"], state["caches"]))
+    (x, arenas), new_caches = jax.lax.scan(
+        group, (x, arenas),
+        (params["blocks"], scanned, jnp.arange(groups, dtype=jnp.int32)))
+    new_caches = tuple(split_layers(a, groups) if c else n
+                       for a, n, c in zip(arenas, new_caches, carried))
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     with jax.named_scope("logits"):
         logits = logits_fn(params, cfg, x)

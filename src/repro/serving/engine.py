@@ -87,7 +87,7 @@ import numpy as np
 
 from repro import telemetry
 from repro.analysis.invariants import declare_invariants
-from repro.kernels.kv_layout import page_count
+from repro.kernels.kv_layout import arena_pages, page_count
 from repro.models import lm
 from repro.serving import sampling as smp
 from repro.serving import state_pool as sp
@@ -330,7 +330,7 @@ class Engine:
         kv_bytes = _kv_bytes(self.pool) + (
             _kv_bytes(self.draft_pool) if self.spec is not None else 0)
         if self.paged:
-            self._kv_page_bytes = kv_bytes // total_pages
+            self._kv_page_bytes = kv_bytes // arena_pages(total_pages)
             self._kv_token_bytes = self._kv_page_bytes // self.page_size
         else:
             self._kv_token_bytes = kv_bytes // (n_slots * max_seq)

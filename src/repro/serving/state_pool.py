@@ -25,7 +25,8 @@ compiled executable serves every slot.
 
 PAGED mode (DESIGN.md §12): ``init_paged_pool`` swaps the per-slot KV
 layout for a global page arena — KV leaves become (groups, n_pages,
-page_size, Hkv, hd) with NO slot axis, and each slot owns a page-table row
+page_size, Hkv, hd) (INT8 scales (groups, n_pages, Hkv*page_size)) with
+NO slot axis, and each slot owns a page-table row
 (host-side, ``PageAllocator``/``PrefixCache``; the engine uploads the table
 per dispatch as ``state["pages"]``). Every slot op takes ``paged=True`` and
 splits cache entries by kind: position-indexed KV entries live in the
@@ -45,17 +46,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import lm
+from repro.models.attention import is_kv_entry  # noqa: F401 (re-export)
 
 SLOT_AXIS = 1   # slot axis of every leaf under pool["caches"]
 TRASH_PAGE = 0  # reserved physical page: inactive dispatch rows' tables are
                 # redirected here so their garbage writes never touch a live
                 # page (the shared arena cannot be select-masked per slot)
-
-
-def is_kv_entry(entry: Any) -> bool:
-    """True for a position-indexed KV cache entry (pageable); False for
-    recurrent Mamba/xLSTM state (slot-resident, O(1) per slot)."""
-    return isinstance(entry, dict) and ("k" in entry or "k_q" in entry)
 
 
 def init_pool(cfg, n_slots: int, max_seq: int, ctx=None,

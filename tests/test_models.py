@@ -186,3 +186,41 @@ def test_markov_determinism_ceiling(vocab, det):
             if sum(c.values()) >= 20]
     if tops:
         assert abs(np.mean(tops) - det) < 0.2
+
+
+# -------------------------------------------------------------- attention
+@pytest.mark.parametrize("qk_norm", [True, False], ids=["qk_norm", "plain"])
+def test_qk_norm_projections_reach_the_norm_unrounded(qk_norm):
+    """With qk-norm, a bf16 model's q and k projections leave the matmul as
+    f32 and reach the norm with no bf16 rounding between: XLA on a TPU skips
+    such a rounding where it fuses the projection with the norm and keeps
+    it where it does not, so a rounded projection would make one layer's K
+    depend on the program around it (the paged engine's against serial
+    decode's). v, and q/k without qk-norm, stay bf16."""
+    from repro.models import attention as A
+    from repro.models import layers as L
+    cfg = dataclasses.replace(configs.get_smoke_config("qwen3-0.6b"),
+                              qk_norm=qk_norm)
+    p = A.attention_init(jax.random.PRNGKey(0), cfg)
+    x = jnp.ones((1, 4, cfg.d_model), L.COMPUTE_DTYPE)
+    positions = jnp.arange(4, dtype=jnp.int32)[None]
+    closed = jax.make_jaxpr(
+        lambda p, x: A.attention_forward(p, cfg, x, positions))(p, x)
+    leaves = jax.tree_util.tree_flatten_with_path((p, x))[0]
+    weight = {v: jax.tree_util.keystr(path)
+              for (path, _), v in zip(leaves, closed.jaxpr.invars)}
+    source = {}                 # a weight's dtype conversions lead back to it
+    out = {}
+    for eqn in closed.jaxpr.eqns:
+        if eqn.primitive.name == "convert_element_type":
+            src = eqn.invars[0]
+            if src in weight or src in source:
+                source[eqn.outvars[0]] = source.get(src, src)
+        elif eqn.primitive.name == "dot_general":
+            w = eqn.invars[1]
+            w = source.get(w, w)
+            if w in weight:
+                out[weight[w].split("'")[1]] = eqn.outvars[0].aval.dtype
+    want = jnp.float32 if qk_norm else L.COMPUTE_DTYPE
+    assert out == {"wq": want, "wk": want, "wv": L.COMPUTE_DTYPE,
+                   "wo": L.COMPUTE_DTYPE}, out

@@ -140,6 +140,73 @@ def test_paged_speculative_rollback_token_identical(setup):
         _assert_drained(eng)
 
 
+# ------------------------------------------------- layer-indexed KV write
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("quantized", [False, True], ids=["uint16", "int8"])
+def test_layer_indexed_paged_write_touches_only_its_positions(quantized,
+                                                              layer):
+    """``update_kv_cache`` on a stacked arena merged into one
+    (``merge_layers``), through a table offset to layer ``layer``, writes
+    that layer at the (page, offset) its table names, scales at lane
+    ``h*page_size + offset`` of the page's row, and nothing else: every
+    other byte of the stacked K, V and scale leaves stays equal. Row 1's
+    table is the trash page throughout (an inactive row): its write lands
+    on that layer's page 0."""
+    from repro.kernels.kv_layout import (arena_pages, merge_layers,
+                                         split_layers, to_store)
+    from repro.models import attention as A
+    groups, ps, hkv, hd, sn = 3, 4, 2, 8, 3
+    key = jax.random.PRNGKey(layer + 10 * quantized)
+    one = A.init_kv_cache(arena_pages(12), ps, hkv, hd, quantized,
+                          paged=True)
+    stacked = {}
+    for i, (name, leaf) in enumerate(sorted(one.items())):
+        shape, k = (groups,) + leaf.shape, jax.random.fold_in(key, i)
+        stacked[name] = (
+            jax.random.uniform(k, shape, jnp.float32, 0.01, 0.1)
+            if leaf.dtype == jnp.float32
+            else jax.random.randint(k, shape, -128, 128).astype(leaf.dtype)
+            if leaf.dtype == jnp.int8
+            else jax.random.bits(k, shape, jnp.uint16))
+    table = jnp.asarray([[3, 7, 5], [0, 0, 0], [11, 2, 9]], jnp.int32)
+    pos = jnp.asarray([2, 5, 7], jnp.int32)
+    k_new, v_new = (jax.random.normal(jax.random.fold_in(key, 9 + i),
+                                      (3, sn, hkv, hd), jnp.bfloat16)
+                    for i in range(2))
+
+    @jax.jit
+    def write(arena, g):
+        merged, n_pages = merge_layers(arena)
+        return split_layers(A.update_kv_cache(merged, k_new, v_new, pos,
+                                              pages=table + g * n_pages),
+                            groups)
+
+    out = write(stacked, jnp.int32(layer))
+    if quantized:
+        # quantized under jit, as the write quantizes
+        quant = jax.jit(A._quant_kv)
+        (kq, ks), (vq, vs) = quant(k_new), quant(v_new)
+        new = {"k_q": kq, "v_q": vq, "k_s": ks, "v_s": vs}
+    else:
+        new = {"k": to_store(k_new, jnp.uint16),
+               "v": to_store(v_new, jnp.uint16)}
+    want = {name: np.array(leaf) for name, leaf in stacked.items()}
+    for b in range(3):
+        for t in range(sn):
+            p = int(pos[b]) + t
+            page, off = int(table[b, p // ps]), p % ps
+            for name, val in new.items():
+                val = np.asarray(val[b, t])
+                if name in ("k_s", "v_s"):
+                    want[name][layer, page, off::ps] = val
+                else:
+                    want[name][layer, page, off] = val
+    assert sorted(out) == sorted(want)
+    for name in want:
+        np.testing.assert_array_equal(np.asarray(out[name]), want[name],
+                                      err_msg=name)
+
+
 # --------------------------------------------------------- prefix sharing
 def test_prefix_reuse_skips_prefill_and_stays_identical(setup):
     """Requests repeating a page-aligned system prompt: later admissions

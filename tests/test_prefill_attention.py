@@ -140,12 +140,16 @@ def test_prefill_kernel_chunk_invariant_bitwise(quantized):
 
 
 def _arena(key, quantized, n_pages, ps):
-    """A paged arena as the engine stores it: uint16 words for bf16 KV."""
-    from repro.kernels.kv_layout import to_store
+    """A paged arena as the engine stores it: uint16 words for bf16 KV,
+    INT8 scales in (n_pages, Hkv*ps) rows."""
+    from repro.kernels.kv_layout import page_scales, to_store
     arena = _cache(key, ps, quantized)        # (B, ps, ...) -> re-lead
     arena = {k: jnp.concatenate([v] * (n_pages // B + 1))[:n_pages]
              for k, v in arena.items()}
-    if not quantized:
+    if quantized:
+        arena.update(k_s=page_scales(arena["k_s"]),
+                     v_s=page_scales(arena["v_s"]))
+    else:
         arena = {k: to_store(v, jnp.uint16) for k, v in arena.items()}
     return arena
 

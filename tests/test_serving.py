@@ -139,9 +139,34 @@ def test_xlstm_engine_matches_serial_token_identical():
     pool = sp.init_pool(cfg, 2, 64, default_ctx(), params=params)
     assert pool["caches"] and all(not sp.is_kv_entry(e)
                                   for e in pool["caches"])
+    _assert_staggered_engine_matches_serial(cfg, params)
+
+
+def test_hybrid_paged_engine_matches_serial_token_identical():
+    """A period mixing Mamba and attention blocks, paged, over three layer
+    groups: each attention arena rides the layer scan's carry and is read
+    and written by layer index, while the Mamba state is sliced per group
+    through ``xs``. The split must keep every entry with its own layer:
+    engine output equals serial (contiguous) decode token-for-token. The
+    experts are left out: with them the engine already differs from serial
+    decode, paged or not (ROADMAP, experts in the engine)."""
+    smoke = configs.get_smoke_config("jamba-1.5-large-398b")
+    cfg = dataclasses.replace(smoke, n_layers=3 * smoke.n_layers,
+                              block_pattern=smoke.block_pattern * 3,
+                              moe=None)
+    params = lm.init_params(jax.random.PRNGKey(1), cfg)
+    eng = _assert_staggered_engine_matches_serial(cfg, params, page_size=8,
+                                                  prefix_cache=False)
+    assert [sp.is_kv_entry(e) for e in eng.pool["caches"]] == [False, True]
+    assert lm.pattern_period(cfg) == 2
+
+
+def _assert_staggered_engine_matches_serial(cfg, params, **engine_kw):
+    """Three requests arriving at ticks 0, 2, 4 on two slots, chunked
+    prefill interleaving decode: each one's tokens equal serial decode's."""
     prompts = _prompts(cfg, [11, 6, 17], seed=5)
     eng = Engine(params, cfg, n_slots=2, max_seq=64,
-                 sched=SchedulerConfig(prefill_chunk=5))
+                 sched=SchedulerConfig(prefill_chunk=5), **engine_kw)
     res = eng.run([Request(prompt=p, max_new_tokens=6) for p in prompts],
                   arrival_ticks=[0, 2, 4])
     for idx, prompt in enumerate(prompts):
@@ -149,6 +174,7 @@ def test_xlstm_engine_matches_serial_token_identical():
         ref = serial_decode(params, cfg, prompt, 6, max_seq=64,
                             decode_rows=eng.n_slots)
         assert res[idx].tokens == ref, (idx, res[idx].tokens, ref)
+    return eng
 
 
 # ------------------------------------------------------------------ pool ops

@@ -11,6 +11,7 @@ the worker given this file loads the TPU library."""
 import dataclasses
 import os
 import pathlib
+import re
 import subprocess
 import sys
 
@@ -67,13 +68,15 @@ def _compile(fn, args) -> None:
     _check(jax.jit(fn).lower(*args).compile())
 
 
-def _kv(spec, lead, length, kind):
+def _kv(spec, lead, length, kind, paged=False):
     """k, v, k_s, v_s shape specs: bf16 (contiguous), uint16 (a paged
-    arena's raw bf16 words) or int8 with f32 per-(pos, head) scales."""
+    arena's raw bf16 words) or int8 with f32 per-(pos, head) scales, which
+    a paged arena keeps as one (Hkv*page_size) row per page."""
     shape = (lead, length, HKV, HD)
     if kind == "int8":
+        s = (lead, HKV * length) if paged else shape[:3]
         return (spec(shape, jnp.int8), spec(shape, jnp.int8),
-                spec(shape[:3], jnp.float32), spec(shape[:3], jnp.float32))
+                spec(s, jnp.float32), spec(s, jnp.float32))
     dt = jnp.uint16 if kind == "uint16" else jnp.bfloat16
     return spec(shape, dt), spec(shape, dt), None, None
 
@@ -91,7 +94,7 @@ def test_cache_attention_compiles(one_chip, sq, paged, int8):
     q = spec((B, HQ, HD) if sq == 1 else (B, sq, HQ, HD), jnp.bfloat16)
     start = spec((B,), jnp.int32)
     if paged:
-        kv = _kv(spec, N_PAGES, PS, "int8" if int8 else "uint16")
+        kv = _kv(spec, N_PAGES, PS, "int8" if int8 else "uint16", paged)
         fn = (paged_decode_attention_pallas if sq == 1
               else paged_prefill_attention_pallas)
         _compile(fn, (q, *kv, start, spec((B, W // PS), jnp.int32)))
@@ -140,7 +143,8 @@ def test_kernel_operations_keep_their_names(one_chip):
     d, f = CFG.d_model, CFG.d_ff
     args = (spec((B, d), jnp.bfloat16), spec((d, f), jnp.int8),
             spec((f,), jnp.float32), spec((B, 1, HQ, HD), jnp.bfloat16),
-            *_kv(spec, N_PAGES, PS, "int8"), spec((B,), jnp.int32),
+            *_kv(spec, N_PAGES, PS, "int8", paged=True),
+            spec((B,), jnp.int32),
             spec((B, W // PS), jnp.int32))
     text = jax.jit(step).lower(*args).compile().as_text()
     calls = {m.group(1).split(".")[0] for m in re.finditer(
@@ -150,12 +154,16 @@ def test_kernel_operations_keep_their_names(one_chip):
                      "quantize_rowwise_pallas"}, calls
 
 
-@pytest.mark.parametrize("hqp", [False, True], ids=["bf16", "hqp_int8"])
-@pytest.mark.parametrize("step", ["engine.prefill", "engine.decode"])
-def test_engine_step_compiles(one_chip, step, hqp):
-    """One whole paged engine step (28-layer scan) on the ``pallas``
-    backend. The backend is resolved when a function is traced, and off
-    the chip it would resolve to ``xla`` — so the test steers it itself."""
+_ENGINE_STEPS: dict = {}
+
+
+def _engine_step(one_chip, step, hqp):
+    """(engine, compiled step): one whole paged engine step (28-layer scan)
+    on the ``pallas`` backend, compiled once for every test that reads it.
+    The backend is resolved when a function is traced, and off the chip it
+    would resolve to ``xla`` — so this steers it itself."""
+    if (step, hqp) in _ENGINE_STEPS:
+        return _ENGINE_STEPS[step, hqp]
     from repro.analysis.hlo_checks import engine_hot_paths
     from repro.compress.quantize import quantize_lm_params
     from repro.kernels.backend import set_backend
@@ -176,10 +184,71 @@ def test_engine_step_compiles(one_chip, step, hqp):
         on_chip = lambda t: jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                            sharding=one_chip), t)
-        _check(fn.lower(*[a if isinstance(a, int) else on_chip(a)
-                          for a in args]).compile())
+        compiled = fn.lower(*[a if isinstance(a, int) else on_chip(a)
+                              for a in args]).compile()
     finally:
         set_backend(prev)
+    _ENGINE_STEPS[step, hqp] = eng, compiled
+    return eng, compiled
+
+
+@pytest.mark.parametrize("hqp", [False, True], ids=["bf16", "hqp_int8"])
+@pytest.mark.parametrize("step", ["engine.prefill", "engine.decode"])
+def test_engine_step_compiles(one_chip, step, hqp):
+    _check(_engine_step(one_chip, step, hqp)[1])
+
+
+# one HLO instruction: `[ROOT] %name = dtype[dims]{layout} opcode(operands`
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s+=\s+(\w+)\[([\d,]*)\]\S*"
+                        r"\s+([\w\-]+)\((.*)$")
+_HLO_OPERAND = re.compile(r"(\w+)\[([\d,]*)\]\S*\s+%")
+
+
+def _arena_moves(hlo_text, pool):
+    """Copies, slices and write-backs of a paged KV arena in optimized HLO
+    (fusion bodies included): a ``copy`` or ``dynamic-slice`` whose result
+    is a stacked KV leaf, one layer of it, or the stack's merged
+    (groups*n_pages, ...) view, and a ``dynamic-update-slice`` that writes
+    such a block back; shapes compare with unit dims dropped (a layer sliced
+    out of the stack is (1, n_pages, ...)). The in-place per-token write is
+    a ``scatter``."""
+    from repro.serving import state_pool as sp
+    dt = {"int8": "s8", "uint16": "u16", "float32": "f32", "bfloat16": "bf16"}
+    arena = set()
+    for entry in pool["caches"]:
+        if sp.is_kv_entry(entry):
+            for leaf in jax.tree.leaves(entry):
+                d, s = dt[leaf.dtype.name], tuple(leaf.shape)
+                arena |= {(d, tuple(x for x in t if x != 1))
+                          for t in (s, s[1:], (s[0] * s[1],) + s[2:])}
+    dims = lambda text: tuple(int(x) for x in text.split(",")
+                              if x and x != "1")
+    moves = []
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if not m:
+            continue
+        d, shape, op, rest = m.group(1), dims(m.group(2)), m.group(3), m.group(4)
+        if op in ("copy", "dynamic-slice") and (d, shape) in arena:
+            moves.append(line.strip()[:160])
+        elif op == "dynamic-update-slice":
+            upd = _HLO_OPERAND.findall(rest)[1:2]
+            if upd and (upd[0][0], dims(upd[0][1])) in arena:
+                moves.append(line.strip()[:160])
+    return moves
+
+
+@pytest.mark.parametrize("hqp", [False, True], ids=["bf16", "hqp_int8"])
+@pytest.mark.parametrize("step", ["engine.prefill", "engine.decode"])
+def test_engine_step_moves_no_arena(one_chip, step, hqp):
+    """The KV arena rides in the layer scan's carry and is written in place
+    by layer index: the compiled step holds no copy, slice or write-back of
+    any stacked KV leaf (uint16 K/V, or INT8 K/V and their f32 scales) nor
+    of one layer's arena."""
+    eng, compiled = _engine_step(one_chip, step, hqp)
+    text = compiled.as_text()
+    assert "scatter(" in text                  # the in-place write is there
+    assert _arena_moves(text, eng.pool) == []
 
 
 def test_chip_smoke_refuses_a_host_without_tpu():

@@ -12,9 +12,12 @@ Device operations are told apart by the name XLA gives each operation
 in the trace, which for a Pallas call is the jitted function the program
 wraps it in (``paged_prefill_attention_pallas.12`` for cache attention in
 prefill and decode, ``int8_matmul_pallas``, ``quantize_rowwise_pallas``):
-the three kernels themselves are all named ``_kernel``. The events of
-control-flow operations (``while`` and the like) enclose their bodies'
-operations and are left out, so that no time counts twice.
+the three kernels themselves are all named ``_kernel``. A configuration
+file may list more classes as ``kernels``, ``[class, name prefix]`` pairs;
+``extract`` keeps a trace's classes in its record, and every reduction
+below classes the trace's operations by them. The events of control-flow
+operations (``while`` and the like) enclose their bodies' operations and
+are left out, so that no time counts twice.
 """
 from __future__ import annotations
 
@@ -49,12 +52,14 @@ class capture:
         return False
 
 
-def extract(path: str, window: Tuple[float, float]) -> dict:
+def extract(path: str, window: Tuple[float, float],
+            kernels: Sequence[Sequence[str]] = ()) -> dict:
     """Read the newest ``.xplane.pb`` under ``path``. ``window`` is the
     traced stretch on the host's monotonic clock, (start, end); the host
     annotation ``bench.window`` marks the same stretch on the trace's
     clock, and every time in the record is relative to its start, in
-    seconds."""
+    seconds. ``kernels``: the configuration's classes, after
+    ``KERNELS``."""
     from jax.profiler import ProfileData
     files = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"),
                              recursive=True), key=os.path.getmtime)
@@ -89,7 +94,7 @@ def extract(path: str, window: Tuple[float, float]) -> dict:
         rec["t"] -= t0
     host = [h for h in host if h["name"] != HOST_PREFIX + "window"]
     return {"window_s": span, "host_t0": window[0], "ops": ops,
-            "host": host}
+            "host": host, "kernels": kernel_classes(kernels)}
 
 
 def clip(rec: dict) -> List[Tuple[float, float, dict]]:
@@ -123,8 +128,13 @@ def busy_s(rec: dict) -> float:
     return sum(union(v) for v in by_dev.values()) / len(by_dev)
 
 
-def kernel_of(op: dict) -> Optional[str]:
-    for cls, needle in KERNELS:
+def kernel_classes(extra: Sequence[Sequence[str]] = ()) -> List[list]:
+    """``KERNELS`` and then a configuration's ``[class, prefix]`` pairs."""
+    return [list(k) for k in KERNELS + tuple(map(tuple, extra))]
+
+
+def kernel_of(op: dict, kernels: Sequence[Sequence[str]]) -> Optional[str]:
+    for cls, needle in kernels:
         if op["name"].startswith(needle):
             return cls
     return None
@@ -132,7 +142,8 @@ def kernel_of(op: dict) -> Optional[str]:
 
 def kernel_seconds(rec: dict, cls: str) -> float:
     """Summed device time of one kernel class inside the window."""
-    return sum(b - a for a, b, op in clip(rec) if kernel_of(op) == cls)
+    return sum(b - a for a, b, op in clip(rec)
+               if kernel_of(op, rec["kernels"]) == cls)
 
 
 def top_ops(rec: dict, n: int = 10) -> List[list]:
@@ -141,7 +152,7 @@ def top_ops(rec: dict, n: int = 10) -> List[list]:
     otherwise."""
     acc: Dict[str, float] = {}
     for a, b, op in clip(rec):
-        key = kernel_of(op) or op["name"].split(".")[0]
+        key = kernel_of(op, rec["kernels"]) or op["name"].split(".")[0]
         acc[key] = acc.get(key, 0.0) + (b - a)
     return [[k, v] for k, v in sorted(acc.items(), key=lambda kv: -kv[1])[:n]]
 

@@ -6,5 +6,4 @@ UNIT = "%"
 
 
 def read(rec):
-    return roofline_share(rec, ["attention"], rec["work_trace"].attention,
-                          "bf16")
+    return roofline_share(rec, ["attention"])

@@ -6,6 +6,4 @@ UNIT = "%"
 
 
 def read(rec):
-    w = rec["work_trace"]
-    return roofline_share(rec, ["w8a8_matmul", "w8a8_quantize"],
-                          w.matmul + w.quantize, "int8")
+    return roofline_share(rec, ["w8a8_matmul", "w8a8_quantize"])

@@ -26,6 +26,10 @@ Every matrix product runs at ``jax.default_matmul_precision("highest")``.
 Departures from the published model: none in the equations; the
 vocabulary rows past ``vocab_size`` that the served embedding table pads
 with are never read.
+
+Besides, ``program_sizes`` and ``work_model``: the sizes the program's
+configuration must have, and the work the served model does (the
+interface is in ``bench/reference/__init__.py``).
 """
 from __future__ import annotations
 
@@ -36,6 +40,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import work
 
 F32 = jnp.float32
 Q_BLOCK = 512          # queries per attention block (bounds score memory)
@@ -70,6 +76,37 @@ class Precision:
 
 
 FLOAT = Precision()
+
+
+# ------------------------------------------------------------ the harness
+def program_sizes(m: dict) -> dict:
+    """The program's ``ModelConfig`` attributes the file's sizes fix."""
+    return {"n_layers": m["num_hidden_layers"], "d_model": m["hidden_size"],
+            "n_heads": m["num_attention_heads"],
+            "n_kv_heads": m["num_key_value_heads"],
+            "resolved_head_dim": m["head_dim"],
+            "d_ff": m["intermediate_size"], "vocab_size": m["vocab_size"],
+            "norm_eps": m["rms_norm_eps"],
+            "rope_theta": float(m["rope_theta"]),
+            "tie_embeddings": m["tie_word_embeddings"], "qk_norm": True}
+
+
+def work_model(dims: Dims, served, int8: bool) -> work.Model:
+    """Every layer alike: GQA cache attention (INT8 K/V with a float32
+    scale per (position, head) where ``int8``), q/k/v/o and the SwiGLU
+    MLP at the served MLP width (the artifact's compacted one where it
+    has one)."""
+    gate = served["blocks"][0]["mlp"]["gate"]
+    f = (gate.w_q if hasattr(gate, "w_q") else gate["w"]).shape[-1]
+    d, q, kv = (dims.d_model, dims.heads * dims.head_dim,
+                dims.kv_heads * dims.head_dim)
+    layer = work.Layer(
+        work.Attention(dims.heads, dims.kv_heads, dims.head_dim,
+                       1 if int8 else 2, 4 if int8 else 0),
+        tuple(work.Linear(k, n) for k, n in
+              [(d, q), (d, kv), (d, kv), (q, d), (d, f), (d, f), (f, d)]))
+    return work.Model((layer,) * dims.layers, dims.d_model, dims.vocab,
+                      8 if int8 else None)
 
 
 # ------------------------------------------------------------ quantisation

@@ -3,10 +3,13 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything is found by name: the cell in ``BENCHMARK.json``; its
-configuration in the file the benchmark names (``bench/configs``); its
-traffic mix in ``bench/traffic/<traffic>.json``, generated by the module
-``bench/traffic/<kind>.py`` that the mix names; each per-layer metric by a
-reader ``bench/metrics/<metric>.py``.
+configuration in the file the benchmark names (``bench/configs``), and the
+plain reference ``bench/reference/<reference>.py`` that the file names,
+which also gives the sizes the program's configuration must hold and the
+served model's work (the interface is in ``bench/reference/__init__.py``);
+its traffic mix in ``bench/traffic/<traffic>.json``, generated by the
+module ``bench/traffic/<kind>.py`` that the mix names; each per-layer
+metric by a reader ``bench/metrics/<metric>.py``.
 
 One run: set-up (runtime start, weights made on the device from the seed,
 the HQP artifact where the configuration asks for it, the engine built the
@@ -36,6 +39,7 @@ T_START = time.monotonic()
 import argparse  # noqa: E402
 import asyncio  # noqa: E402
 import contextlib  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -110,22 +114,18 @@ class Setup:
 
 
 # ------------------------------------------------------------ the system
-def program_config(cfg_file: dict):
-    """The program's configuration, checked against the file's sizes."""
+def program_config(cfg_file: dict, ref):
+    """The program's configuration, checked against the sizes the
+    reference's ``program_sizes`` reads from the file."""
     from repro import configs
     get = (configs.get_smoke_config if cfg_file.get("preset") == "smoke"
            else configs.get_config)
     pc = get(cfg_file["arch"])
-    m = cfg_file["model"]
-    want = {"n_layers": m["num_hidden_layers"], "d_model": m["hidden_size"],
-            "n_heads": m["num_attention_heads"],
-            "n_kv_heads": m["num_key_value_heads"],
-            "resolved_head_dim": m["head_dim"],
-            "d_ff": m["intermediate_size"], "vocab_size": m["vocab_size"],
-            "norm_eps": m["rms_norm_eps"],
-            "rope_theta": float(m["rope_theta"]),
-            "tie_embeddings": m["tie_word_embeddings"], "qk_norm": True}
-    have = {k: getattr(pc, k) for k in want}
+    want = ref.program_sizes(cfg_file["model"])
+    if not want:
+        raise SystemExit(f"the reference {cfg_file['reference']!r} gives no "
+                         "size to check the program's configuration by")
+    have = {k: functools.reduce(getattr, k.split("."), pc) for k in want}
     if have != want:
         raise SystemExit(f"program config {cfg_file['arch']} is not the "
                          f"file's: {have} != {want}")
@@ -281,19 +281,6 @@ def end_to_end(records, window, drain_end):
     return out, len(due), sum(not ok(r) for r in due)
 
 
-def model_for_work(dims, int8, params):
-    """The served model's sizes for the work counts (the artifact's
-    compacted MLP width where it has one)."""
-    from repro.compress import QuantizedLinear
-    gate = params["blocks"][0]["mlp"]["gate"]
-    d_ff = (gate.w_q if isinstance(gate, QuantizedLinear)
-            else gate["w"]).shape[-1]
-    return work.Model(dims.layers, dims.d_model, dims.heads, dims.kv_heads,
-                      dims.head_dim, d_ff, dims.vocab,
-                      8 if int8 else None, 1 if int8 else 2,
-                      4 if int8 else 0)
-
-
 def peaks_for(kind: str) -> dict:
     table = json.loads((BENCH / "peaks.json").read_text())
     if kind not in table["devices"]:
@@ -342,7 +329,7 @@ def open_system(cell, seed, trace, check_chip=True, t_start=None,
         S.ref = ref = load_module(BENCH / "reference" /
                                   f"{cfg_file['reference']}.py")
         S.gen = load_module(BENCH / "traffic" / f"{mix['kind']}.py")
-    pc = program_config(cfg_file)
+    pc = program_config(cfg_file, ref)
     S.dims = dims = ref.Dims.from_hf(cfg_file["model"])
     S.hqp = "hqp" in cfg_file
     S.params = build_params(cfg_file, pc, seed, setup)
@@ -356,8 +343,8 @@ def open_system(cell, seed, trace, check_chip=True, t_start=None,
         log(f"[setup] artifact {S.art.manifest.bytes_before} -> "
             f"{S.art.manifest.bytes_after} bytes, theta "
             f"{S.art.manifest.theta:.4f}, {S.art.manifest.n_drop} units "
-            f"dropped ({S.ref_drop} by the reference), d_ff "
-            f"{model_for_work(dims, True, S.served).d_ff}")
+            f"dropped ({S.ref_drop} by the reference)")
+    S.work = ref.work_model(dims, S.served, S.hqp)
     with setup.step("engine"):
         S.eng, eargs = build_engine(cfg_file, mix, pc, ctx, S.served)
     wrng = np.random.default_rng([seed % 2 ** 64, 3])
@@ -414,7 +401,8 @@ def serve_window(S, plan, seconds):
                     ta = time.monotonic()
                     time.sleep(min(TRACE_S, seconds / 2))
                     tb = time.monotonic()
-            S.tr = devtrace.extract(str(tdir), (ta, tb))
+            S.tr = devtrace.extract(str(tdir), (ta, tb),
+                                    S.cell["config"].get("kernels", ()))
             S.trace_window = (ta, tb)
         out, _ = child.communicate(timeout=drain_end - time.monotonic() + 30)
     finally:
@@ -437,13 +425,12 @@ def layer_metrics(S, window, result, device):
     prompt_len = {s["uid"]: s["args"]["prompt_len"]
                   for s in S.tracer.records
                   if s["type"] == "span" and s["name"] == "request"}
-    wm = model_for_work(S.dims, S.hqp, S.served)
     rec = {"window": window, "spans": S.tracer.records, "steps": S.steps,
            "compile_times": S.compile_times, "trace": tr,
            "n_slots": S.eng.n_slots, "peaks": peaks,
-           "work_window": work.count(wm, S.tracer.records, prompt_len,
+           "work_window": work.count(S.work, S.tracer.records, prompt_len,
                                      *window),
-           "work_trace": work.count(wm, S.tracer.records, prompt_len,
+           "work_trace": work.count(S.work, S.tracer.records, prompt_len,
                                     *S.trace_window)}
     units = {m["name"]: m["unit"] for m in S.cell["per_layer"]}
     metrics = {}
@@ -451,14 +438,13 @@ def layer_metrics(S, window, result, device):
         v = load_module(BENCH / "metrics" / f"{m['name']}.py").read(rec)
         if v is not None:
             metrics[m["name"]] = {"value": v, "unit": units[m["name"]]}
-    for name, calls, prec in (("attention", rec["work_trace"].attention,
-                               "bf16"),
-                              ("w8a8", rec["work_trace"].matmul, "int8")):
-        if calls:
-            least, bound = work.least_time(calls, peaks["flops"][prec],
-                                           peaks["hbm_bytes_per_s"])
-            log(f"[roofline] {name}: least time {least:.6f}s, bound by "
-                f"{bound}")
+    wt = rec["work_trace"]
+    for name, calls in wt.calls.items():
+        least, bound = work.least_time(calls,
+                                       peaks["flops"][wt.precision[name]],
+                                       peaks["hbm_bytes_per_s"])
+        log(f"[roofline] {name}: least time {least:.6f}s, bound by {bound}, "
+            f"device {devtrace.kernel_seconds(tr, name):.6f}s")
     device.update(busy_s=devtrace.busy_s(tr), window_s=tr["window_s"])
     result["breakdown"] = {"device_ops": devtrace.top_ops(tr),
                            "idle_gaps": devtrace.idle_gaps(tr)}

@@ -3,7 +3,9 @@
     python -m pytest -q bench/test_devtrace.py
 
 ``testdata/trace_v5e.json`` is ``devtrace.extract`` of a ``--trace 1`` run
-of ``hqp-chat`` on one TPU v5e, cut to its first part.
+of ``hqp-chat`` on one TPU v5e, cut to its first part; it was recorded
+before a record carried its kernel classes, and is given ``KERNELS``, as
+``extract`` gives a cell whose file lists none.
 """
 import json
 import pathlib
@@ -19,7 +21,9 @@ from bench import devtrace  # noqa: E402
 
 @pytest.fixture(scope="module")
 def rec():
-    return json.loads((BENCH / "testdata" / "trace_v5e.json").read_text())
+    return dict(json.loads((BENCH / "testdata" / "trace_v5e.json")
+                           .read_text()),
+                kernels=devtrace.kernel_classes())
 
 
 def sweep_union(intervals):
@@ -79,3 +83,35 @@ def test_top_ops_are_the_largest(rec):
     assert [v for _, v in top] == sorted((v for _, v in top), reverse=True)
     assert sum(v for _, v in top) <= sum(b - a for a, b, _ in
                                          devtrace.clip(rec)) * (1 + 1e-9)
+
+
+# what KERNELS' classes read on this trace before a configuration could
+# add classes of its own
+TODAY = {"attention": 0.022688699000000003,
+         "w8a8_matmul": 0.0027781759999998216,
+         "w8a8_quantize": 5.1324999999874735e-05}
+
+
+def test_todays_classes_read_the_same_seconds(rec):
+    assert {k: devtrace.kernel_seconds(rec, k) for k in TODAY} == TODAY
+
+
+def test_a_configuration_files_class_classes_its_operations(rec):
+    """``tiny-jamba.json`` declares ``selective_scan``; the trace's plain
+    ``fusion`` operations, renamed to its prefix, are classed by it where
+    the record carries the file's classes (as ``extract`` makes it), and
+    by nothing where it carries ``KERNELS`` alone."""
+    cfg = json.loads((BENCH / "testdata" / "tiny-jamba.json").read_text())
+    (cls, prefix), = cfg["kernels"]
+    plain = lambda op: op["name"].split(".")[0] == "fusion"  # noqa: E731
+    fusion_s = sum(b - a for a, b, op in devtrace.clip(rec) if plain(op))
+    ops = [dict(op, name=f"{prefix}.{i}") if plain(op) else op
+           for i, op in enumerate(rec["ops"])]
+    mine = dict(rec, ops=ops,
+                kernels=devtrace.kernel_classes(cfg["kernels"]))
+    assert fusion_s > 0
+    assert devtrace.kernel_seconds(mine, cls) == pytest.approx(fusion_s)
+    assert devtrace.kernel_seconds(dict(rec, ops=ops), cls) == 0
+    assert {k: devtrace.kernel_seconds(mine, k) for k in TODAY} == TODAY
+    top = dict(devtrace.top_ops(mine))
+    assert top[cls] == pytest.approx(fusion_s) and "fusion" not in top
